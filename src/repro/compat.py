@@ -1,85 +1,32 @@
-"""JAX version-compatibility layer (DESIGN: portability subsystem).
+"""The JAX API surface whose names have moved between JAX releases.
 
-The production target is current JAX on TPU, but the reproduction must run
-— and be CI-tested — on stock CPU JAX back to the pinned 0.4.37.  Every
-API that drifted between those generations is feature-detected here once
-and re-exported under a single stable name; *no other module in this repo
-may import the drifted symbols directly*.
-
-Covered drift points:
-
-  * ``shard_map``            0.4.x: ``jax.experimental.shard_map.shard_map``
-                             with ``check_rep``; current: ``jax.shard_map``
-                             with ``check_vma``.
-  * ``AxisType`` +           0.4.x ``jax.make_mesh`` has no ``axis_types``
-    ``make_mesh``            kwarg and ``jax.sharding.AxisType`` does not
-                             exist; current has both.
-  * ``use_mesh``             current: ``jax.set_mesh`` context manager;
-                             interim: ``jax.sharding.use_mesh``; 0.4.x:
-                             the ``Mesh`` object's own context manager.
-  * Pallas TPU surface       ``pltpu.TPUCompilerParams`` was renamed
-                             ``pltpu.CompilerParams``; the TPU import can
-                             fail entirely on minimal CPU builds.
-
-The resolver helpers take the module/function to probe as an argument so
-tests can exercise both API generations by passing fakes
-(tests/test_compat.py) without caring which JAX is installed.
+The repo supports the installed JAX (0.9).  The symbols below are the
+ones whose home or spelling has drifted across releases (``shard_map``,
+``make_mesh``/``AxisType``, the mesh context, the Pallas TPU surface);
+every other module imports them from here, never from JAX directly
+(reprolint RL001), so the next drift is absorbed in this one file.
 """
 from __future__ import annotations
 
 import contextlib
-import enum
-import inspect
 from typing import Any, Callable, Optional
 
 import jax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import AxisType
 
 __all__ = [
-    "AxisType", "HAS_PALLAS", "HAS_PALLAS_TPU", "axis_index",
-    "cost_analysis", "default_backend", "is_tpu", "jax_version", "make_mesh",
-    "make_mesh_exact", "pallas_compiler_params", "pl", "pltpu",
-    "resolve_shard_map", "shard_map", "supports_axis_types", "use_mesh",
+    "AxisType", "axis_index", "cost_analysis", "is_tpu", "make_mesh",
+    "make_mesh_exact", "pallas_compiler_params", "pl", "pltpu", "shard_map",
+    "use_mesh",
 ]
 
 
-def jax_version() -> tuple:
-    """(major, minor, patch) of the installed JAX."""
-    return tuple(int(p) for p in jax.__version__.split(".")[:3])
-
-
-# ---------------------------------------------------------------------------
-# AxisType / make_mesh
-# ---------------------------------------------------------------------------
-
-try:
-    from jax.sharding import AxisType  # current JAX
-except ImportError:  # 0.4.x: stub with the same member names
-    class AxisType(enum.Enum):  # type: ignore[no-redef]
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-
-
-def supports_axis_types(make_mesh_fn: Callable) -> bool:
-    """Does this ``make_mesh`` accept the ``axis_types`` kwarg?"""
-    try:
-        return "axis_types" in inspect.signature(make_mesh_fn).parameters
-    except (TypeError, ValueError):
-        return False
-
-
-def make_mesh(axis_shapes, axis_names, *, axis_types=None, devices=None,
-              _make: Optional[Callable] = None):
-    """``jax.make_mesh`` that silently drops ``axis_types`` on old JAX
-    (0.4.x meshes have no axis-type concept; every axis behaves as Auto,
-    which is exactly what this repo requests)."""
-    make = _make if _make is not None else jax.make_mesh
-    kwargs: dict = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if axis_types is not None and supports_axis_types(make):
-        kwargs["axis_types"] = axis_types
-    return make(axis_shapes, axis_names, **kwargs)
+def make_mesh(axis_shapes, axis_names, *, axis_types=None, devices=None):
+    """``jax.make_mesh`` (which may order devices for ring collectives)."""
+    return jax.make_mesh(axis_shapes, axis_names, axis_types=axis_types,
+                         devices=devices)
 
 
 def make_mesh_exact(device_grid, axis_names):
@@ -89,155 +36,43 @@ def make_mesh_exact(device_grid, axis_names):
     ``jax.make_mesh`` may permute devices for ring-efficient collectives;
     multi-pod meshes must NOT be permuted — the pod axis has to stay the
     process axis or a pod's shards land behind another process's memory.
-    ``axis_types`` is deliberately not taken: its constructor format
-    drifted (0.4.x dict vs current tuple) and the default — every axis
-    Auto — is the only thing this repo uses."""
+    Every axis is Auto, the only axis type this repo uses."""
     from jax.sharding import Mesh
     return Mesh(device_grid, axis_names)
 
 
-# ---------------------------------------------------------------------------
-# shard_map
-# ---------------------------------------------------------------------------
-
-def resolve_shard_map(jax_mod: Any = None) -> tuple[Callable, str]:
-    """(shard_map callable, name of its replication-check kwarg).
-
-    Current JAX exports ``jax.shard_map(..., check_vma=...)``; 0.4.x has
-    ``jax.experimental.shard_map.shard_map(..., check_rep=...)``."""
-    mod = jax_mod if jax_mod is not None else jax
-    fn = getattr(mod, "shard_map", None)
-    if fn is not None:
-        try:
-            params = inspect.signature(fn).parameters
-        except (TypeError, ValueError):
-            params = {}
-        return fn, ("check_vma" if "check_vma" in params else "check_rep")
-    from jax.experimental.shard_map import shard_map as legacy
-    return legacy, "check_rep"
-
-
-_SHARD_MAP: Optional[tuple[Callable, str]] = None
-
-
 def shard_map(f: Callable, *, mesh, in_specs, out_specs,
               check_vma: Optional[bool] = None) -> Callable:
-    """Version-stable ``shard_map``.  ``check_vma`` follows the current
-    spelling; it is forwarded as ``check_rep`` on 0.4.x."""
-    global _SHARD_MAP
-    if _SHARD_MAP is None:
-        _SHARD_MAP = resolve_shard_map()
-    fn, check_kw = _SHARD_MAP
-    kwargs = {"mesh": mesh, "in_specs": in_specs, "out_specs": out_specs}
-    if check_vma is not None:
-        kwargs[check_kw] = check_vma
-    return fn(f, **kwargs)
+    """``jax.shard_map``; ``check_vma=None`` keeps JAX's default."""
+    kwargs = {} if check_vma is None else {"check_vma": check_vma}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)
 
-
-# ---------------------------------------------------------------------------
-# mesh context
-# ---------------------------------------------------------------------------
 
 @contextlib.contextmanager
-def use_mesh(mesh, _jax: Any = None):
-    """Enter ``mesh`` as the ambient mesh, whatever this JAX calls that:
-    ``jax.set_mesh`` (current), ``jax.sharding.use_mesh`` (interim), or the
-    ``Mesh`` object's own context manager (0.4.x)."""
-    mod = _jax if _jax is not None else jax
-    setter = getattr(mod, "set_mesh", None)
-    if setter is None:
-        setter = getattr(getattr(mod, "sharding", None), "use_mesh", None)
-    cm = setter(mesh) if setter is not None else mesh
-    if not hasattr(cm, "__enter__"):
-        # a bare global setter (already applied): undo on exit so callers
-        # that iterate meshes don't compile under a stale one
-        try:
-            yield mesh
-        finally:
-            try:
-                setter(None)
-            except Exception:  # this JAX can't clear it; leave as-is
-                pass
-        return
-    with cm:
+def use_mesh(mesh):
+    """Enter ``mesh`` as the ambient mesh (``jax.set_mesh``)."""
+    with jax.set_mesh(mesh):
         yield mesh
 
 
 def axis_index(axis_names) -> Any:
-    """Flattened index of this shard over one or more mapped mesh axes.
-
-    Current ``jax.lax.axis_index`` accepts a tuple of names and returns
-    the row-major flattened index; 0.4.x only takes a single name.  This
-    builds the flattened index from single-axis calls (axis sizes via the
-    constant-foldable ``psum(1, name)``), so row-major order over e.g.
-    ``("pod", "data")`` matches the block order of a leading array axis
-    sharded with ``PartitionSpec(("pod", "data"), ...)`` on every
-    supported JAX."""
-    if isinstance(axis_names, str):
-        return jax.lax.axis_index(axis_names)
-    idx = None
-    for name in axis_names:
-        i = jax.lax.axis_index(name)
-        idx = i if idx is None else idx * jax.lax.psum(1, name) + i
-    if idx is None:
-        raise ValueError("axis_index needs at least one axis name")
-    return idx
+    """Flattened index of this shard over one or more mapped mesh axes,
+    row-major, so it matches the block order of a leading array axis
+    sharded with ``PartitionSpec(("pod", "data"), ...)``."""
+    return jax.lax.axis_index(axis_names)
 
 
 def cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` as a flat dict.  Current JAX returns a
-    dict; 0.4.x returns a single-element list of dicts."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost) if cost else {}
-
-
-# ---------------------------------------------------------------------------
-# backend probes
-# ---------------------------------------------------------------------------
-
-def default_backend() -> str:
-    return jax.default_backend()
+    """``compiled.cost_analysis()`` as a plain dict (empty when the backend
+    reports nothing)."""
+    return dict(compiled.cost_analysis() or {})
 
 
 def is_tpu() -> bool:
-    return default_backend() == "tpu"
+    return jax.default_backend() == "tpu"
 
 
-# ---------------------------------------------------------------------------
-# Pallas import surface
-# ---------------------------------------------------------------------------
-
-try:
-    from jax.experimental import pallas as pl
-    HAS_PALLAS = True
-except ImportError:  # minimal builds without Pallas at all
-    pl = None  # type: ignore[assignment]
-    HAS_PALLAS = False
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    HAS_PALLAS_TPU = True
-except ImportError:
-    pltpu = None  # type: ignore[assignment]
-    HAS_PALLAS_TPU = False
-
-
-def pallas_compiler_params(_pltpu: Any = None, **kwargs):
-    """Build the TPU compiler-params struct under either of its names
-    (``CompilerParams`` today, ``TPUCompilerParams`` on 0.4.x), dropping
-    any field the installed class does not know.  Returns None when the
-    Pallas TPU surface is unavailable (``pallas_call`` accepts that)."""
-    mod = _pltpu if _pltpu is not None else pltpu
-    if mod is None:
-        return None
-    cls = getattr(mod, "CompilerParams", None) or getattr(
-        mod, "TPUCompilerParams", None)
-    if cls is None:
-        return None
-    try:
-        return cls(**kwargs)
-    except TypeError:
-        known = inspect.signature(cls).parameters
-        return cls(**{k: v for k, v in kwargs.items() if k in known})
+def pallas_compiler_params(**kwargs):
+    """The Pallas TPU compiler-params struct (``pltpu.CompilerParams``)."""
+    return pltpu.CompilerParams(**kwargs)

@@ -182,7 +182,9 @@ class ArchConfig:
         s = self.semisfl.split_layer
         if s <= 0:
             s = max(1, self.num_layers // 4)
-        return min(s, self.num_layers - 1)
+        # a CNN may put every conv on the client (the paper's CNN@2,
+        # AlexNet@5, VGG13@10, VGG16@13): its top still holds the FC stack
+        return min(s, self.num_layers - (self.arch_type != "cnn"))
 
     def param_count(self) -> int:
         """Analytic total parameter count (used by roofline + comm model)."""

@@ -31,6 +31,7 @@ Two executors drive the cross-entity phase:
     tests/test_shard_clients.py)."""
 from __future__ import annotations
 
+import functools
 import os
 from dataclasses import dataclass
 from typing import Any, Callable, NamedTuple, Optional
@@ -272,6 +273,22 @@ class SemiSFLSystem:
                                   pool_features(self.cfg, feats))
         return out["logits"], z, feats
 
+    def _at_config_precision(self, fn: Callable) -> Callable:
+        """Trace ``fn`` with the configuration's matmul precision.  A
+        float32 model computes its matmuls and convolutions in float32 on
+        every backend; the TPU's default for float32 operands is a single
+        bfloat16 pass, under which paper-vgg16 diverges at lr 0.02 within
+        two rounds.  The CPU computes float32 either way."""
+        if jnp.dtype(self.cfg.dtype) != jnp.float32:
+            return fn
+
+        @functools.wraps(fn)
+        def traced(*args):
+            with jax.default_matmul_precision("highest"):
+                return fn(*args)
+
+        return traced
+
     def _build_steps(self):
         cfg, s = self.cfg, self.s
         # Only stochastic-layer archs (FC dropout on the AlexNet/VGG
@@ -325,6 +342,7 @@ class SemiSFLSystem:
                                      step=state.step + 1)
             return new_state, loss
 
+        supervised_step = self._at_config_precision(supervised_step)
         self.supervised_step = jax.jit(supervised_step)
         self.supervised_phase = scan_phase(supervised_step)
         # raw (unjitted) step, for building phase variants with explicit
@@ -446,6 +464,7 @@ class SemiSFLSystem:
                          teacher, queue, rng, step + 1)
             return new_carry, (loss, h, mask_rate)
 
+        semi_step = self._at_config_precision(semi_step)
         self.semi_step = jax.jit(semi_step)
         self.semi_phase = scan_phase(semi_step)
 
@@ -553,7 +572,7 @@ class SemiSFLSystem:
                          teacher, queue, rng, step + 1)
             return new_carry, (loss, h, mask_rate)
 
-        self.semi_step_sharded = semi_step_sharded
+        self.semi_step_sharded = self._at_config_precision(semi_step_sharded)
         if self._use_sharded:
             self._build_sharded_exec()
 
@@ -562,7 +581,7 @@ class SemiSFLSystem:
             logits, _, _ = self._forward(params, x, train=False)
             return (logits.argmax(-1) == y).astype(jnp.float32).sum()
 
-        self.eval_batch = jax.jit(eval_batch)
+        self.eval_batch = jax.jit(self._at_config_precision(eval_batch))
 
     def _build_sharded_exec(self):
         """Compile the client-sharded executor: the shard_map'd scan phase

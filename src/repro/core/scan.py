@@ -96,9 +96,10 @@ def pinned_scan_phase(step_fn: Callable[[Carry, Batch], Tuple[Carry, Any]],
 
     This is the phase shape for steps that mix a *manual* ``shard_map``
     subregion with GSPMD model-parallel computation (the model-sharded LM
-    train step in ``launch/steps.py``): on the pinned JAX 0.4.37, XLA's
-    SPMD partitioner rejects ``while`` loops inside partially-manual
-    regions (``Check failed: sharding.IsManualSubgroup()``), so the scan
+    train step in ``launch/steps.py``): on JAX 0.4.37, the repo's former
+    floor, XLA's SPMD partitioner rejects ``while`` loops inside
+    partially-manual regions (``Check failed:
+    sharding.IsManualSubgroup()``), so the scan
     must stay OUTSIDE the manual region — the step body enters/leaves its
     own fully-manual ``shard_map`` each iteration, and the layer-stack
     scans inside the model run under plain GSPMD.
@@ -141,11 +142,8 @@ def sharded_scan_phase(step_fn: Callable[[Carry, Batch], Tuple[Carry, Any]],
 
     ``carry_specs`` / ``batch_specs`` / ``out_specs`` are PartitionSpec
     pytrees matching ``carry``, ``batches`` and the stacked per-step
-    outputs (see ``repro.sharding.specs.semi_carry_pspecs``).  Goes
-    through ``repro.compat.shard_map`` so JAX 0.4.37 and current both
-    work; the replication check is disabled because replicated outputs
-    are established via psum, which 0.4.x ``check_rep`` cannot always
-    prove."""
+    outputs (see ``repro.sharding.specs.semi_carry_pspecs``).  The replication
+    check is off: replicated outputs are established via psum."""
     from repro.compat import shard_map
 
     if unroll is None:

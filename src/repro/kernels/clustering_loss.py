@@ -45,22 +45,21 @@ def _fwd_kernel(z_ref, pseudo_ref, aok_ref, qz_ref, qlab_ref, qmask_ref,
     logits = jax.lax.dot_general(z, qz, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
     logits = logits * inv_temp                              # (bB, bQ)
-    valid = qmask_ref[...] > 0                              # (bQ,) 1=valid
+    valid = qmask_ref[...] > 0                              # (1, bQ) 1=valid
     conf = qmask_ref[...] > 1                               # 2=valid+conf
-    lm = jnp.where(valid[None, :], logits, NEG_INF)
+    lm = jnp.where(valid, logits, NEG_INF)
 
     m_prev = m_scr[:, :1]
     m_new = jnp.maximum(m_prev, jnp.max(lm, axis=1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.where(valid[None, :], jnp.exp(lm - m_new), 0.0)
+    p = jnp.where(valid, jnp.exp(lm - m_new), 0.0)
     l_scr[...] = jnp.broadcast_to(
         alpha * l_scr[:, :1] + jnp.sum(p, axis=1, keepdims=True),
         l_scr.shape)
     m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
 
-    pos = (pseudo_ref[...][:, None] == qlab_ref[...][None, :])
-    pos &= conf[None, :]
-    pos &= (aok_ref[...] > 0)[:, None]
+    pos = (pseudo_ref[:, :1] == qlab_ref[...]) & conf      # (bB, bQ)
+    pos &= aok_ref[:, :1] > 0
     posf = pos.astype(jnp.float32)
     ps_scr[...] += jnp.broadcast_to(
         jnp.sum(jnp.where(pos, logits, 0.0), axis=1, keepdims=True),
@@ -94,10 +93,9 @@ def _bwd_kernel(z_ref, pseudo_ref, aok_ref, qz_ref, qlab_ref, qmask_ref,
     valid = qmask_ref[...] > 0
     conf = qmask_ref[...] > 1
     lse = lse_ref[:, :1]
-    w = jnp.where(valid[None, :], jnp.exp(logits - lse), 0.0)  # softmax
-    pos = (pseudo_ref[...][:, None] == qlab_ref[...][None, :])
-    pos &= conf[None, :]
-    pos &= (aok_ref[...] > 0)[:, None]
+    w = jnp.where(valid, jnp.exp(logits - lse), 0.0)     # softmax
+    pos = (pseudo_ref[:, :1] == qlab_ref[...]) & conf      # (bB, bQ)
+    pos &= aok_ref[:, :1] > 0
     n_pos = n_pos_ref[:, :1]
     has = n_pos > 0.0
     coef = jnp.where(has, (w - pos.astype(jnp.float32)
@@ -120,45 +118,49 @@ def _pad_to(x: Array, n: int, axis: int = 0, fill=0):
     return jnp.pad(x, widths, constant_values=fill)
 
 
-def _run_fwd(z, pseudo, aok, qz, qlab, qmask, inv_temp, block_b, block_q,
-             interpret):
+def _layout(z, pseudo, aok, qz, qlab, qmask, block_b, block_q):
+    """Pad to whole tiles and lay the per-anchor and per-queue-entry
+    vectors out in 2-D blocks Mosaic accepts: anchor vectors as lane-dense
+    (b_pad, 128) columns, queue vectors as (1, q_pad) rows.  Padded anchors
+    carry pseudo-label -1 and padded queue rows label -2, so a pad never
+    counts as a positive."""
     b, d = z.shape
     q = qz.shape[0]
     bb = min(block_b, b)
     bq = min(block_q, q)
     b_pad = -(-b // bb) * bb
     q_pad = -(-q // bq) * bq
-    z = _pad_to(z, b_pad)
-    pseudo = _pad_to(pseudo, b_pad, fill=-1)
-    aok = _pad_to(aok, b_pad)
-    qz = _pad_to(qz, q_pad)
-    qlab = _pad_to(qlab, q_pad, fill=-2)
-    qmask = _pad_to(qmask, q_pad)
-    grid = (b_pad // bb, q_pad // bq)
+    col = lambda v, fill=0: _pad_to(
+        jnp.broadcast_to(v[:, None], (b, 128)), b_pad, fill=fill)
+    row = lambda v, fill=0: _pad_to(v, q_pad, fill=fill)[None, :]
+    args = (_pad_to(z, b_pad), col(pseudo, -1), col(aok),
+            _pad_to(qz, q_pad), row(qlab, -2), row(qmask))
+    col_spec = pl.BlockSpec((bb, 128), lambda i, j: (i, 0))
+    row_spec = pl.BlockSpec((1, bq), lambda i, j: (0, j))
+    specs = [pl.BlockSpec((bb, d), lambda i, j: (i, 0)), col_spec, col_spec,
+             pl.BlockSpec((bq, d), lambda i, j: (j, 0)), row_spec, row_spec]
+    return args, specs, col, col_spec, (b_pad // bb, q_pad // bq), bb
+
+
+def _run_fwd(z, pseudo, aok, qz, qlab, qmask, inv_temp, block_b, block_q,
+             interpret):
+    b = z.shape[0]
+    args, specs, _, col_spec, grid, bb = _layout(
+        z, pseudo, aok, qz, qlab, qmask, block_b, block_q)
     kernel = functools.partial(_fwd_kernel, inv_temp=inv_temp,
                                n_q_blocks=grid[1])
     outs = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((bb, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((bb,), lambda i, j: (i,)),
-            pl.BlockSpec((bb,), lambda i, j: (i,)),
-            pl.BlockSpec((bq, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((bq,), lambda i, j: (j,)),
-            pl.BlockSpec((bq,), lambda i, j: (j,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bb, 128), lambda i, j: (i, 0)),
-            pl.BlockSpec((bb, 128), lambda i, j: (i, 0)),
-            pl.BlockSpec((bb, 128), lambda i, j: (i, 0)),
-        ],
-        out_shape=[jax.ShapeDtypeStruct((b_pad, 128), jnp.float32)] * 3,
+        in_specs=specs,
+        out_specs=[col_spec] * 3,
+        out_shape=[jax.ShapeDtypeStruct((grid[0] * bb, 128),
+                                        jnp.float32)] * 3,
         scratch_shapes=[pltpu.VMEM((bb, 128), jnp.float32)] * 4,
         interpret=interpret,
         compiler_params=pallas_compiler_params(
             dimension_semantics=("parallel", "arbitrary")),
-    )(z, pseudo, aok, qz, qlab, qmask)
+    )(*args)
     pos_sum, n_pos, lse = (o[:b, 0] for o in outs)
     return pos_sum, n_pos, lse
 
@@ -166,43 +168,21 @@ def _run_fwd(z, pseudo, aok, qz, qlab, qmask, inv_temp, block_b, block_q,
 def _run_bwd(z, pseudo, aok, qz, qlab, qmask, lse, n_pos, gscale, inv_temp,
              block_b, block_q, interpret):
     b, d = z.shape
-    q = qz.shape[0]
-    bb = min(block_b, b)
-    bq = min(block_q, q)
-    b_pad = -(-b // bb) * bb
-    q_pad = -(-q // bq) * bq
-    zp = _pad_to(z, b_pad)
-    pseudo = _pad_to(pseudo, b_pad, fill=-1)
-    aok = _pad_to(aok, b_pad)
-    qzp = _pad_to(qz, q_pad)
-    qlab = _pad_to(qlab, q_pad, fill=-2)
-    qmask = _pad_to(qmask, q_pad)
-    pad128 = lambda v: _pad_to(jnp.broadcast_to(v[:, None], (b, 128)), b_pad)
-    grid = (b_pad // bb, q_pad // bq)
+    args, specs, col, col_spec, grid, bb = _layout(
+        z, pseudo, aok, qz, qlab, qmask, block_b, block_q)
     kernel = functools.partial(_bwd_kernel, inv_temp=inv_temp,
                                n_q_blocks=grid[1])
     dz = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((bb, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((bb,), lambda i, j: (i,)),
-            pl.BlockSpec((bb,), lambda i, j: (i,)),
-            pl.BlockSpec((bq, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((bq,), lambda i, j: (j,)),
-            pl.BlockSpec((bq,), lambda i, j: (j,)),
-            pl.BlockSpec((bb, 128), lambda i, j: (i, 0)),
-            pl.BlockSpec((bb, 128), lambda i, j: (i, 0)),
-            pl.BlockSpec((bb, 128), lambda i, j: (i, 0)),
-        ],
+        in_specs=specs + [col_spec] * 3,
         out_specs=pl.BlockSpec((bb, d), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b_pad, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((grid[0] * bb, d), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bb, d), jnp.float32)],
         interpret=interpret,
         compiler_params=pallas_compiler_params(
             dimension_semantics=("parallel", "arbitrary")),
-    )(zp, pseudo, aok, qzp, qlab, qmask, pad128(lse), pad128(n_pos),
-      pad128(gscale))
+    )(*args, col(lse), col(n_pos), col(gscale))
     return dz[:b]
 
 

@@ -126,9 +126,4 @@ def call(name: str, *args, backend: Optional[str] = None,
     if mode == "ref" or (k.supports is not None
                          and not k.supports(*args, **kwargs)):
         return k.ref(*args, **kwargs)
-    if not compat.HAS_PALLAS_TPU:
-        raise RuntimeError(
-            f"kernel backend {mode!r} requested for {name!r} but the Pallas "
-            f"TPU import surface is unavailable in this JAX build; use "
-            f"{ENV_VAR}=ref (or auto) instead")
     return k.pallas(*args, interpret=(mode == "interpret"), **kwargs)

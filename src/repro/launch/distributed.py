@@ -82,26 +82,18 @@ def enable_cpu_collectives(impl: Optional[str] = None) -> Optional[str]:
 
     Must run before the CPU client is created — jaxlib builds the client
     with or without a collectives implementation once.  The env knob is
-    ``REPRO_CPU_COLLECTIVES`` (``gloo`` | ``mpi`` | ``none``); JAX's own
-    ``JAX_CPU_COLLECTIVES_IMPLEMENTATION`` env var is NOT read by the
-    pinned 0.4.37, so this goes through ``jax.config.update``.  Returns
-    the implementation selected, or None when the knob does not exist
-    (very old jaxlib) or was explicitly disabled."""
+    ``REPRO_CPU_COLLECTIVES`` (``gloo`` | ``mpi`` | ``none``).  Returns
+    the implementation selected, or None when it was explicitly
+    disabled."""
     import jax
 
     impl = impl or os.environ.get(ENV_CPU_COLLECTIVES, "gloo")
     if impl in ("none", "off", ""):
         return None
-    # belt and braces: newer JAX reads the env var at import; the pinned
-    # 0.4.37 only honors the config knob
-    os.environ.setdefault("JAX_CPU_COLLECTIVES_IMPLEMENTATION", impl)
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", impl)
-    except AttributeError:      # knob unknown to this JAX: nothing to set
-        return None
     # a ValueError (explicitly requested but invalid value) propagates:
     # silently degrading to no collectives backend would surface as an
     # opaque hang/crash at the first cross-process psum instead
+    jax.config.update("jax_cpu_collectives_implementation", impl)
     return impl
 
 
@@ -339,16 +331,24 @@ def spawn_local(num_processes: int, argv: Optional[Sequence[str]] = None, *,
 
     ``python -m repro.launch.train --num-processes 2 ...`` uses this when
     no process id is set: the parent only spawns and waits — children see
-    ``REPRO_PROCESS_ID`` and take the initialize path."""
+    ``REPRO_PROCESS_ID`` and take the initialize path.
+
+    The fleet is a CPU rig: it starts only when the children's
+    environment pins ``JAX_PLATFORMS=cpu``.  On an accelerator host each
+    child would claim every chip; there one process drives all of the
+    host's chips (``--shard-clients``)."""
     import time
 
+    env = dict(os.environ, **(env_extra or {}))
+    if env.get("JAX_PLATFORMS") != "cpu":
+        raise RuntimeError(
+            "a localhost fleet runs on CPU only: set JAX_PLATFORMS=cpu.  "
+            "On a TPU host one process drives every chip; run a single "
+            "process with --shard-clients instead of --num-processes")
     argv = list(sys.argv if argv is None else argv)
     coordinator = coordinator or f"127.0.0.1:{free_port()}"
-    env = dict(os.environ)
     env[ENV_NUM_PROCESSES] = str(num_processes)
     env[ENV_COORDINATOR] = coordinator
-    if env_extra:
-        env.update(env_extra)
     procs = []
     for p in range(num_processes):
         child_env = dict(env, **{ENV_PROCESS_ID: str(p)})

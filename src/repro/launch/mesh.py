@@ -4,9 +4,6 @@ Defined as functions — importing this module never touches JAX device
 state.  Single pod: (data=16, model=16) = 256 chips (TPU v5e pod slice);
 multi-pod: (pod=2, data=16, model=16) = 512 chips, the ``pod`` axis being
 an outer data-parallel axis (client groups / gradient all-reduce span it).
-
-Mesh construction goes through ``repro.compat`` so the same code runs on
-JAX 0.4.37 (no ``axis_types``) and current JAX.
 """
 from __future__ import annotations
 

@@ -307,9 +307,9 @@ def make_train_step(plan: StepPlan, dist: DistContext,
     split link: features leave the region client-sharded, the masked-mean
     CE is written in sum form (explicit global numerator/denominator), and
     the cotangent at the cut re-enters the region through the shard_map
-    transpose.  The scan over K stays outside (the pinned JAX 0.4.37
-    cannot partition ``while`` inside partially-manual regions, so manual
-    and model-parallel code may not nest — see
+    transpose.  The scan over K stays outside (JAX 0.4.37, the repo's
+    former floor, cannot partition ``while`` inside partially-manual
+    regions, so manual and model-parallel code may not nest — see
     ``core/scan.py::pinned_scan_phase``)."""
     cfg = plan.cfg
     s = cfg.semisfl

@@ -16,6 +16,7 @@ import argparse
 import os
 import time
 from dataclasses import dataclass
+from pathlib import Path
 from typing import Optional
 
 import numpy as np
@@ -44,6 +45,23 @@ _WIRE_BASELINE_ERR = ("--wire-format compresses the split-link payloads; "
                       "have no split link")
 
 
+def init_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is read by JAX itself and no
+    other directory is set here.  Otherwise the cache lives at a fixed
+    path inside the checkout (``<repo>/.jax_cache``, git-ignored): the
+    directory is part of each entry's key, so it must not move between
+    runs."""
+    import jax
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        return env_dir
+    path = str(Path(__file__).resolve().parents[3] / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def build_system(name: str, cfg, **kw):
     if name == "semisfl":
         return SemiSFLSystem(cfg, **kw)
@@ -57,6 +75,22 @@ def build_system(name: str, cfg, **kw):
     return BASELINES[name](cfg, **kw)
 
 
+def train_config(arch: str, *, smoke: bool, k_s: int, k_u: int):
+    """The configuration ``run_training`` trains.  The smoke rig shrinks
+    the queue and speeds up the Eq. (10) controller; a full configuration
+    keeps its own ``queue_len``, ``observation_period`` and
+    ``adaptation_window``."""
+    from dataclasses import replace
+    if smoke:
+        cfg = smoke_config(arch)
+        cfg = replace(cfg, semisfl=replace(
+            cfg.semisfl, queue_len=512, observation_period=3,
+            adaptation_window=3))
+    else:
+        cfg = get_config(arch)
+    return replace(cfg, semisfl=replace(cfg.semisfl, k_s_init=k_s, k_u=k_u))
+
+
 def run_training(arch: str = "paper-cnn", baseline: str = "semisfl",
                  rounds: int = 30, n_labeled: int = 250,
                  n_total: int = 2400, n_clients: int = 10,
@@ -68,11 +102,7 @@ def run_training(arch: str = "paper-cnn", baseline: str = "semisfl",
                  shard_clients: bool | None = None,
                  wire_format: str | None = None,
                  n_pods: int = 1, log=print):
-    from dataclasses import replace
-    cfg = smoke_config(arch) if smoke else get_config(arch)
-    cfg = replace(cfg, semisfl=replace(
-        cfg.semisfl, k_s_init=k_s, k_u=k_u, queue_len=512,
-        observation_period=3, adaptation_window=3))
+    cfg = train_config(arch, smoke=smoke, k_s=k_s, k_u=k_u)
     if cfg.arch_type != "cnn":
         raise SystemExit("train.py drives the classification rig; "
                          "LM-task steps are exercised via dryrun/examples")
@@ -121,9 +151,9 @@ def run_training(arch: str = "paper-cnn", baseline: str = "semisfl",
 
     history = []
     for r in range(rounds):
-        t0 = time.time()
+        t0 = time.perf_counter()
         state, m = sys_.run_round(state, lab, cls, ctrl, rng_np=sel_rng)
-        rec = {"round": r, "k_s": ctrl.k_s, "dt": round(time.time() - t0, 2)}
+        rec = {"round": r, "k_s": ctrl.k_s, "dt": time.perf_counter() - t0}
         if r % eval_every == 0 or r == rounds - 1:
             acc = sys_.evaluate(state, test.x, test.y)
             if not isinstance(m, dict):
@@ -338,6 +368,7 @@ def build_parser() -> argparse.ArgumentParser:
 def main(argv: Optional[list] = None) -> None:
     args = build_parser().parse_args(argv)
     settings = resolve_settings(args)
+    init_compile_cache()
 
     if settings.spawn:
         # parent of a localhost fleet: fork one child per pod (they see

@@ -9,8 +9,8 @@ import pytest
 def pytest_configure(config):
     config.addinivalue_line(
         "markers",
-        "tpu: needs real TPU hardware (Mosaic-compiled Pallas); "
-        "auto-skipped when jax.default_backend() is not 'tpu'")
+        "tpu: needs real TPU hardware (Mosaic-compiled Pallas); such "
+        "tests request the `tpu` fixture, which skips them elsewhere")
     if not config.pluginmanager.hasplugin("timeout"):
         # tests annotate explicit caps; without pytest-timeout installed
         # the marker is inert but must still be known
@@ -30,17 +30,18 @@ def pytest_collection_modifyitems(config, items):
         for item in items:
             if item.get_closest_marker("timeout") is None:
                 item.add_marker(pytest.mark.timeout(900))
-    if not any(item.get_closest_marker("tpu") for item in items):
-        return
+
+
+@pytest.fixture
+def tpu():
+    """Skip unless JAX's default backend is a TPU.  Decided when a test
+    that asks for it runs, never while collecting: each collecting worker
+    would otherwise initialize a backend, and on a TPU host claim the
+    chip."""
     from repro.compat import is_tpu
-    if is_tpu():
-        return
-    skip = pytest.mark.skip(
-        reason="requires TPU (jax default backend is "
-               "not 'tpu'; compiled-Pallas path untestable here)")
-    for item in items:
-        if item.get_closest_marker("tpu"):
-            item.add_marker(skip)
+    if not is_tpu():
+        pytest.skip("requires TPU (jax default backend is not 'tpu'; "
+                    "compiled-Pallas path untestable here)")
 
 
 @pytest.fixture

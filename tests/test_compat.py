@@ -1,8 +1,5 @@
-"""The JAX portability layer: symbol resolution under both API
-generations (faked — independent of the installed JAX), the kernel
-backend knob, and the mesh-context shim against the real JAX."""
-import types
-
+"""The JAX API boundary (``repro.compat``) against the installed JAX, and
+the kernel backend knob."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -16,37 +13,19 @@ from repro.kernels import dispatch
 # shard_map resolution
 # ---------------------------------------------------------------------------
 
-def test_resolve_shard_map_new_api_check_vma():
-    def new_style(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return ("new", f, mesh, check_vma)
+def test_resolve_shard_map_new_api_check_vma(monkeypatch):
+    # check_vma is forwarded when given and left to JAX's default otherwise
+    calls = []
 
-    fake = types.SimpleNamespace(shard_map=new_style)
-    fn, kw = compat.resolve_shard_map(fake)
-    assert fn is new_style
-    assert kw == "check_vma"
+    def fake(f, *, mesh, in_specs, out_specs, **kw):
+        calls.append(kw)
+        return f
 
-
-def test_resolve_shard_map_top_level_but_old_kwarg():
-    # a mid-generation jax: top-level shard_map that still says check_rep
-    def mid_style(f, *, mesh, in_specs, out_specs, check_rep=True):
-        return ("mid", check_rep)
-
-    fn, kw = compat.resolve_shard_map(types.SimpleNamespace(
-        shard_map=mid_style))
-    assert fn is mid_style
-    assert kw == "check_rep"
-
-
-def test_resolve_shard_map_legacy_fallback():
-    # no top-level shard_map at all -> the experimental one, check_rep.
-    # Only reachable on a JAX that still ships the experimental module
-    # (real 0.4.x always does); skip where it has been removed.
-    legacy_mod = pytest.importorskip(
-        "jax.experimental.shard_map",
-        reason="this JAX no longer has the legacy shard_map module")
-    fn, kw = compat.resolve_shard_map(types.SimpleNamespace())
-    assert fn is legacy_mod.shard_map
-    assert kw == "check_rep"
+    monkeypatch.setattr(jax, "shard_map", fake)
+    compat.shard_map(abs, mesh=None, in_specs=(), out_specs=(),
+                     check_vma=False)
+    compat.shard_map(abs, mesh=None, in_specs=(), out_specs=())
+    assert calls == [{"check_vma": False}, {}]
 
 
 def test_shard_map_wrapper_runs_on_installed_jax():
@@ -64,32 +43,18 @@ def test_shard_map_wrapper_runs_on_installed_jax():
 # make_mesh / AxisType
 # ---------------------------------------------------------------------------
 
-def test_make_mesh_drops_axis_types_on_old_signature():
+def test_make_mesh_passes_axis_types_on_new_signature(monkeypatch):
     calls = {}
 
-    def old_make(axis_shapes, axis_names):  # 0.4.x: no axis_types kwarg
-        calls["args"] = (axis_shapes, axis_names)
+    def fake_make(axis_shapes, axis_names, axis_types=None, *, devices=None):
+        calls.update(axis_types=axis_types, devices=devices)
         return "mesh"
 
-    assert not compat.supports_axis_types(old_make)
-    out = compat.make_mesh((2, 2), ("a", "b"),
-                           axis_types=(compat.AxisType.Auto,) * 2,
-                           _make=old_make)
-    assert out == "mesh"
-    assert calls["args"] == ((2, 2), ("a", "b"))
-
-
-def test_make_mesh_passes_axis_types_on_new_signature():
-    calls = {}
-
-    def new_make(axis_shapes, axis_names, *, devices=None, axis_types=None):
-        calls["axis_types"] = axis_types
-        return "mesh"
-
-    assert compat.supports_axis_types(new_make)
+    monkeypatch.setattr(jax, "make_mesh", fake_make)
     types_ = (compat.AxisType.Auto, compat.AxisType.Auto)
-    compat.make_mesh((2, 2), ("a", "b"), axis_types=types_, _make=new_make)
-    assert calls["axis_types"] == types_
+    assert compat.make_mesh((2, 2), ("a", "b"), axis_types=types_,
+                            devices="devs") == "mesh"
+    assert calls == {"axis_types": types_, "devices": "devs"}
 
 
 def test_axis_type_has_auto_member():
@@ -106,10 +71,13 @@ def test_make_mesh_real_jax_single_device():
 # use_mesh
 # ---------------------------------------------------------------------------
 
-def test_use_mesh_prefers_set_mesh():
+def test_use_mesh_prefers_set_mesh(monkeypatch):
     entered = []
 
     class _Cm:
+        def __init__(self, mesh):
+            entered.append(mesh)
+
         def __enter__(self):
             entered.append("enter")
             return self
@@ -118,36 +86,18 @@ def test_use_mesh_prefers_set_mesh():
             entered.append("exit")
             return False
 
-    fake = types.SimpleNamespace(set_mesh=lambda mesh: _Cm())
-    with compat.use_mesh("mesh-object", _jax=fake):
-        assert entered == ["enter"]
-    assert entered == ["enter", "exit"]
+    monkeypatch.setattr(jax, "set_mesh", _Cm)
+    with compat.use_mesh("mesh-object") as m:
+        assert m == "mesh-object"
+        assert entered == ["mesh-object", "enter"]
+    assert entered == ["mesh-object", "enter", "exit"]
 
 
 def test_use_mesh_bare_setter_is_undone_on_exit():
-    calls = []
-    fake = types.SimpleNamespace(set_mesh=lambda mesh: calls.append(mesh))
-    with compat.use_mesh("mesh-object", _jax=fake):
-        assert calls == ["mesh-object"]
-    assert calls == ["mesh-object", None]  # cleared on exit
-
-
-def test_use_mesh_falls_back_to_mesh_context_manager():
-    entered = []
-
-    class _Mesh:
-        def __enter__(self):
-            entered.append("enter")
-            return self
-
-        def __exit__(self, *a):
-            entered.append("exit")
-            return False
-
-    fake = types.SimpleNamespace(sharding=types.SimpleNamespace())
-    with compat.use_mesh(_Mesh(), _jax=fake):
-        pass
-    assert entered == ["enter", "exit"]
+    mesh = compat.make_mesh((1,), ("d",))
+    with compat.use_mesh(mesh):
+        assert jax.sharding.get_abstract_mesh().axis_names == ("d",)
+    assert jax.sharding.get_abstract_mesh().axis_names == ()
 
 
 def test_use_mesh_real_jax():
@@ -162,60 +112,16 @@ def test_use_mesh_real_jax():
 # pallas compiler params
 # ---------------------------------------------------------------------------
 
-def test_pallas_compiler_params_old_and_new_names():
-    class NewParams:
-        def __init__(self, dimension_semantics=None):
-            self.dimension_semantics = dimension_semantics
-
-    class OldParams(NewParams):
-        pass
-
-    new_mod = types.SimpleNamespace(CompilerParams=NewParams)
-    old_mod = types.SimpleNamespace(TPUCompilerParams=OldParams)
-    got_new = compat.pallas_compiler_params(
-        new_mod, dimension_semantics=("parallel",))
-    got_old = compat.pallas_compiler_params(
-        old_mod, dimension_semantics=("parallel",))
-    assert isinstance(got_new, NewParams)
-    assert isinstance(got_old, OldParams)
-    assert got_old.dimension_semantics == ("parallel",)
-
-
-def test_pallas_compiler_params_drops_unknown_fields():
-    class Strict:
-        def __init__(self, known=None):
-            self.known = known
-
-    mod = types.SimpleNamespace(CompilerParams=Strict)
-    got = compat.pallas_compiler_params(mod, known=1, unknown_field=2)
-    assert got.known == 1
-
-
 def test_pallas_compiler_params_real_jax():
     got = compat.pallas_compiler_params(
         dimension_semantics=("parallel", "arbitrary"))
-    if compat.HAS_PALLAS_TPU:
-        assert got is not None
-    else:
-        assert got is None
+    assert isinstance(got, compat.pltpu.CompilerParams)
+    assert tuple(got.dimension_semantics) == ("parallel", "arbitrary")
 
 
 # ---------------------------------------------------------------------------
 # cost_analysis
 # ---------------------------------------------------------------------------
-
-def test_cost_analysis_dict_under_both_generations():
-    class OldCompiled:  # 0.4.x: list of dicts
-        def cost_analysis(self):
-            return [{"flops": 7.0}]
-
-    class NewCompiled:  # current: plain dict
-        def cost_analysis(self):
-            return {"flops": 7.0}
-
-    assert compat.cost_analysis(OldCompiled()) == {"flops": 7.0}
-    assert compat.cost_analysis(NewCompiled()) == {"flops": 7.0}
-
 
 def test_cost_analysis_real_jax():
     compiled = jax.jit(lambda x: x @ x).lower(
@@ -274,12 +180,9 @@ def test_dispatch_routes_per_backend(monkeypatch):
             "interpret" if interpret else "pallas") or x)
     try:
         dispatch.call("_test_kernel", 1, backend="ref")
-        if compat.HAS_PALLAS_TPU:
-            dispatch.call("_test_kernel", 1, backend="interpret")
-            dispatch.call("_test_kernel", 1, backend="pallas")
-            assert seen == ["ref", "interpret", "pallas"]
-        else:
-            assert seen == ["ref"]
+        dispatch.call("_test_kernel", 1, backend="interpret")
+        dispatch.call("_test_kernel", 1, backend="pallas")
+        assert seen == ["ref", "interpret", "pallas"]
     finally:
         dispatch._REGISTRY.pop("_test_kernel")
 

@@ -1,8 +1,8 @@
 """Backend parity of the dispatched kernels: for every kernel the
 reference path and the Pallas interpret path must agree (fwd, and bwd for
 the differentiable clustering loss) through the *public* dispatched entry
-points in ``repro.kernels``.  Compiled-Mosaic parity runs under the ``tpu``
-marker and is auto-skipped off-TPU (tests/conftest.py)."""
+points in ``repro.kernels``.  Compiled-Mosaic parity asks for the ``tpu``
+fixture and skips off-TPU (tests/conftest.py)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -155,7 +155,7 @@ def test_below_granularity_shapes_fall_back_to_ref_under_any_backend():
 
 
 @pytest.mark.tpu
-def test_clustering_loss_compiled_mosaic_matches_ref():
+def test_clustering_loss_compiled_mosaic_matches_ref(tpu):
     """Mosaic-compiled parity — only meaningful on real TPU hardware."""
     z, args = _clustering_case(128, 512, 32, 5, seed=99)
     loss_ref = kernels.clustering_loss(z, *args, 0.1, backend="ref")

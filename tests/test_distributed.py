@@ -520,6 +520,30 @@ def test_initialize_validation_errors():
         dist.initialize(env={"REPRO_NUM_PROCESSES": "two"})
 
 
+def test_spawn_local_refuses_without_cpu_pin():
+    # a fresh interpreter without JAX_PLATFORMS: the spawner refuses
+    # before starting any child, and no JAX backend is ever initialized
+    script = textwrap.dedent("""
+        from jax._src import xla_bridge
+        from repro.launch.distributed import spawn_local
+        try:
+            spawn_local(2, argv=["-c", "raise SystemExit(3)"])
+        except RuntimeError as e:
+            assert "JAX_PLATFORMS=cpu" in str(e), e
+            assert "--shard-clients" in str(e), e
+        else:
+            raise SystemExit("spawned a fleet without JAX_PLATFORMS=cpu")
+        assert not xla_bridge.backends_are_initialized()
+        print("REFUSED")
+    """)
+    r = subprocess.run([sys.executable, "-c", script], capture_output=True,
+                       text=True, env={"PYTHONPATH": "src",
+                                       "PATH": "/usr/bin:/bin"},
+                       cwd=".", timeout=120)
+    assert r.returncode == 0, r.stdout + r.stderr
+    assert "REFUSED" in r.stdout
+
+
 def test_pod_index_single_process_mesh():
     import jax
 

@@ -210,28 +210,29 @@ def test_semi_carry_pspecs_shapes():
         specs = semi_carry_pspecs(carry, axes)
         (b_s, tb_s, top_s, proj_s, te_s, q_s, rng_s, step_s) = specs
         # client-stacked bottoms: leading axis over the data axes only
-        assert tuple(b_s["convs"][0]["w"]) == (axes, None, None, None, None)
-        assert tuple(b_s["convs"][0]["b"]) == (axes, None)
+        # compared as PartitionSpecs, which normalize ("data",) to "data"
+        assert b_s["convs"][0]["w"] == P(axes, None, None, None, None)
+        assert b_s["convs"][0]["b"] == P(axes, None)
         assert tb_s == b_s
         # server state replicates, rank-matched
-        assert tuple(top_s["cls"]["w"]) == (None, None)
-        assert tuple(proj_s["w"]) == (None, None)
-        assert tuple(te_s["bottom"]["w"]) == (None, None, None, None)
-        assert tuple(q_s.z) == (None, None)
-        assert tuple(q_s.ptr) == ()
-        assert tuple(rng_s) == (None,)
-        assert tuple(step_s) == ()
+        assert top_s["cls"]["w"] == P(None, None)
+        assert proj_s["w"] == P(None, None)
+        assert te_s["bottom"]["w"] == P(None, None, None, None)
+        assert q_s.z == P(None, None)
+        assert q_s.ptr == P()
+        assert rng_s == P(None)
+        assert step_s == P()
 
 
 def test_client_batch_pspec_client_dims():
     from repro.sharding.specs import client_batch_pspec
 
     # LM-task arg_shardings: client axis leading
-    assert tuple(client_batch_pspec(4, ("data",))) == \
-        (("data",), None, None, None)
+    assert client_batch_pspec(4, ("data",)) == \
+        P(("data",), None, None, None)
     # scanned (K, N, B, H, W, C) stacks: client axis 1
-    assert tuple(client_batch_pspec(6, ("pod", "data"), client_dim=1)) == \
-        (None, ("pod", "data"), None, None, None, None)
+    assert client_batch_pspec(6, ("pod", "data"), client_dim=1) == \
+        P(None, ("pod", "data"), None, None, None, None)
 
 
 def test_leading_axis_pspecs_ignores_model_rules():
@@ -243,7 +244,7 @@ def test_leading_axis_pspecs_ignores_model_rules():
     # carry keeps per-client params whole on their shard
     tree = {"attn": {"wq": jnp.zeros((4, 64, 128))}}
     specs = leading_axis_pspecs(tree, ("data",))
-    assert tuple(specs["attn"]["wq"]) == (("data",), None, None)
+    assert specs["attn"]["wq"] == P(("data",), None, None)
 
 
 def test_replicated_pspecs_rank_matched():

@@ -133,6 +133,22 @@ def test_shard_model_invalid_combos_fail_fast():
         settings(["--shard-model", "2"], {"REPRO_SHARD_CLIENTS": "0"})
 
 
+def test_full_config_keeps_its_queue_and_controller():
+    from repro.configs import get_config
+    from repro.launch.train import train_config
+
+    full = train_config("paper-vgg16", smoke=False, k_s=15, k_u=4).semisfl
+    ref = get_config("paper-vgg16").semisfl
+    assert (full.queue_len, full.observation_period,
+            full.adaptation_window) == (2048, ref.observation_period,
+                                        ref.adaptation_window)
+    assert (full.k_s_init, full.k_u) == (15, 4)
+    # the smoke rig keeps its short-run overrides
+    smoke = train_config("paper-vgg16", smoke=True, k_s=15, k_u=4).semisfl
+    assert (smoke.queue_len, smoke.observation_period,
+            smoke.adaptation_window) == (512, 3, 3)
+
+
 def test_prefetch_baseline_gate():
     with pytest.raises(SystemExit, match="phase stacks"):
         settings(["--prefetch", "--baseline", "semifl"])

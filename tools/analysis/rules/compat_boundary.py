@@ -1,12 +1,10 @@
 """RL001 — version-drifted JAX APIs only via ``src/repro/compat.py``.
 
-The repo runs on stock CPU JAX back to 0.4.37 *and* current JAX; every
-API that drifted between the two (``shard_map``'s home and check kwarg,
-``make_mesh``'s ``axis_types``, ``AxisType`` itself, the mesh-context
-spelling, the Pallas TPU compiler-params class) is feature-detected once
-in ``compat.py``.  A direct import anywhere else compiles fine on the
-developer's JAX and breaks on the other generation — in CI at best, on
-the fleet at worst.
+The APIs whose home or spelling has moved between JAX releases
+(``shard_map``'s home and check kwarg, ``make_mesh``'s ``axis_types``,
+``AxisType`` itself, the mesh-context spelling, the Pallas TPU
+compiler-params class) are imported once, in ``compat.py``, so the next
+move is absorbed in one file instead of at every call site.
 """
 from __future__ import annotations
 
