@@ -58,6 +58,7 @@ from repro.data.pipeline import (Loader, PodClients, select_pod_blocked,
 from repro.data.prefetch import RoundPrefetcher, prefetch_default
 from repro.kernels import clustering_loss as fused_clustering_loss
 from repro.models import build_model
+from repro.obs import span, spanned
 from repro.optim import apply_updates, sgd
 
 Array = jax.Array
@@ -81,15 +82,16 @@ def _host(x) -> np.ndarray:
     selection RNG in lockstep.  The multi-process read delegates to
     ``distributed.fetch``, which refuses a non-replicated output loudly
     (a local slice would silently desynchronize the fleet's
-    controllers)."""
+    controllers).  A read of a device value is the span ``semisfl.sync``."""
     if isinstance(x, jax.Array):
-        if not x.is_fully_addressable:
-            from repro.launch.distributed import fetch
-            return fetch(x)
-        # explicit device read: stays legal under
-        # jax.transfer_guard("disallow"), which the parity tests use to
-        # catch IMPLICIT syncs sneaking into the hot path
-        return np.asarray(jax.device_get(x))
+        with span("sync"):
+            if not x.is_fully_addressable:
+                from repro.launch.distributed import fetch
+                return fetch(x)
+            # explicit device read: stays legal under
+            # jax.transfer_guard("disallow"), which the parity tests use
+            # to catch IMPLICIT syncs sneaking into the hot path
+            return np.asarray(jax.device_get(x))
     return np.asarray(x)
 
 
@@ -344,7 +346,8 @@ class SemiSFLSystem:
 
         supervised_step = self._at_config_precision(supervised_step)
         self.supervised_step = jax.jit(supervised_step)
-        self.supervised_phase = scan_phase(supervised_step)
+        self.supervised_phase = scan_phase(supervised_step,
+                                           name="supervised_phase")
         # raw (unjitted) step, for building phase variants with explicit
         # scan policies (benchmarks/roofline.py scan-unroll micro-bench)
         self._supervised_step_fn = supervised_step
@@ -466,7 +469,7 @@ class SemiSFLSystem:
 
         semi_step = self._at_config_precision(semi_step)
         self.semi_step = jax.jit(semi_step)
-        self.semi_phase = scan_phase(semi_step)
+        self.semi_phase = scan_phase(semi_step, name="cross_entity_phase")
 
         # ------- step (5) with top-k sparsified bottom deltas --------------
         # Each client uploads the top-frac entries of its delta against the
@@ -616,7 +619,8 @@ class SemiSFLSystem:
         out_specs = (P(None), P(None), P(None))     # stacked loss/h/mask
         self.semi_phase_sharded = sharded_scan_phase(
             self.semi_step_sharded, mesh=mesh, carry_specs=carry_specs,
-            batch_specs=batch_specs, out_specs=out_specs)
+            batch_specs=batch_specs, out_specs=out_specs,
+            name="cross_entity_phase")
 
         # (K, N, B, ...) prefetch stacks land client-sharded on the mesh;
         # the label stack is never consumed by the phase — don't ship it
@@ -757,6 +761,7 @@ class SemiSFLSystem:
         """Step (5): FedAvg over the client axis."""
         return jax.tree.map(lambda t: t.mean(axis=0), client_bottoms)
 
+    @spanned("round")
     def run_round(self, state: SemiSFLState, labeled: Loader,
                   client_loaders_: list[Loader], controller: FreqController,
                   active: Optional[list[int]] = None,
@@ -836,7 +841,9 @@ class SemiSFLSystem:
         if pf is not None:
             xs_d, ys_d = pf.get_supervised(k_s)   # already on device
             if self.scan_rounds:
-                state, losses_s = self.supervised_phase(state, (xs_d, ys_d))
+                with span("phase.supervised"):
+                    state, losses_s = self.supervised_phase(state,
+                                                            (xs_d, ys_d))
                 f_s_acc = losses_s    # sync deferred past speculate()
             else:
                 f_s_acc = []
@@ -844,22 +851,27 @@ class SemiSFLSystem:
                     # static slice, not `xs_d[i]`: integer indexing
                     # commits the index constant (an implicit transfer
                     # the parity tests' guard rejects)
-                    state, loss = self.supervised_step(
-                        state, (jax.lax.index_in_dim(xs_d, i, keepdims=False),
-                                jax.lax.index_in_dim(ys_d, i,
-                                                     keepdims=False)))
+                    with span("phase.supervised"):
+                        state, loss = self.supervised_step(
+                            state,
+                            (jax.lax.index_in_dim(xs_d, i, keepdims=False),
+                             jax.lax.index_in_dim(ys_d, i, keepdims=False)))
                     f_s_acc.append(float(_host(loss)))
         elif self.scan_rounds:
-            xs, ys = labeled.next_many(k_s)
-            state, losses_s = self.supervised_phase(state,
-                                                    self._sup_put(xs, ys))
+            with span("batch.labeled"):
+                batch = self._sup_put(*labeled.next_many(k_s))
+            with span("phase.supervised"):
+                state, losses_s = self.supervised_phase(state, batch)
+            del batch             # its device buffers free once consumed
             f_s_acc = _host(losses_s)             # one host sync per phase
         else:
             f_s_acc = []
             for _ in range(k_s):
-                x, y = labeled.next()
-                state, loss = self.supervised_step(
-                    state, (jnp.asarray(x), jnp.asarray(y)))
+                with span("batch.labeled"):
+                    x, y = labeled.next()
+                    batch = (jnp.asarray(x), jnp.asarray(y))
+                with span("phase.supervised"):
+                    state, loss = self.supervised_step(state, batch)
                 f_s_acc.append(float(_host(loss)))
 
         # (2) broadcast
@@ -892,11 +904,12 @@ class SemiSFLSystem:
                         "rounds need a pod-blocked active list "
                         "(select_pod_blocked)")
             stack_active = pc.local_indices(active)
-        if self._use_sharded:
-            bottoms, t_bottoms = self._broadcast_sharded(
-                state.params["bottom"], state.teacher["bottom"])
-        else:
-            bottoms, t_bottoms = self.broadcast(state)
+        with span("broadcast"):
+            if self._use_sharded:
+                bottoms, t_bottoms = self._broadcast_sharded(
+                    state.params["bottom"], state.teacher["bottom"])
+            else:
+                bottoms, t_bottoms = self.broadcast(state)
 
         # (3)-(4) cross-entity phase
         carry = (bottoms, t_bottoms, state.params["top"],
@@ -907,37 +920,50 @@ class SemiSFLSystem:
         elif pf is not None:
             xus = pf.get_clients(stack_active, k_u)  # on device/shards
             if self._use_sharded:
-                carry, (losses_u, _h, masks) = self.semi_phase_sharded(
-                    carry, xus)
+                with span("phase.cross_entity"):
+                    carry, (losses_u, _h, masks) = self.semi_phase_sharded(
+                        carry, xus)
             elif self.scan_rounds:
-                carry, (losses_u, _h, masks) = self.semi_phase(carry, xus)
+                with span("phase.cross_entity"):
+                    carry, (losses_u, _h, masks) = self.semi_phase(carry,
+                                                                   xus)
             else:
                 losses_u, masks = [], []
                 for i in range(k_u):
-                    carry, (loss, _h, mask_rate) = self.semi_step(
-                        carry, jax.lax.index_in_dim(xus, i, keepdims=False))
+                    with span("phase.cross_entity"):
+                        carry, (loss, _h, mask_rate) = self.semi_step(
+                            carry,
+                            jax.lax.index_in_dim(xus, i, keepdims=False))
                     losses_u.append(float(_host(loss)))
                     masks.append(float(_host(mask_rate)))
             f_u_acc, mask_acc = losses_u, masks   # sync deferred
         elif self._use_sharded:
-            xus, _ = stack_client_batches_many(
-                client_loaders_, stack_active, k_u,
-                shardings=self._stack_shardings)
-            carry, (losses_u, _h, masks) = self.semi_phase_sharded(
-                carry, xus)
+            with span("batch.clients"):
+                xus, _ = stack_client_batches_many(
+                    client_loaders_, stack_active, k_u,
+                    shardings=self._stack_shardings)
+            with span("phase.cross_entity"):
+                carry, (losses_u, _h, masks) = self.semi_phase_sharded(
+                    carry, xus)
             f_u_acc, mask_acc = _host(losses_u), _host(masks)
         elif self.scan_rounds:
-            xus, _ = stack_client_batches_many(client_loaders_,
-                                               stack_active, k_u)
-            carry, (losses_u, _h, masks) = self.semi_phase(
-                carry, jnp.asarray(xus))
-            f_u_acc, mask_acc = np.asarray(losses_u), np.asarray(masks)
+            with span("batch.clients"):
+                xus, _ = stack_client_batches_many(client_loaders_,
+                                                   stack_active, k_u)
+                xus = jnp.asarray(xus)
+            with span("phase.cross_entity"):
+                carry, (losses_u, _h, masks) = self.semi_phase(carry, xus)
+            del xus
+            f_u_acc, mask_acc = _host(losses_u), _host(masks)
         else:
             f_u_acc, mask_acc = [], []
             for _ in range(k_u):
-                xu, _ = stack_client_batches(client_loaders_, stack_active)
-                carry, (loss, _h, mask_rate) = self.semi_step(
-                    carry, jnp.asarray(xu))
+                with span("batch.clients"):
+                    xu, _ = stack_client_batches(client_loaders_,
+                                                 stack_active)
+                    xu = jnp.asarray(xu)
+                with span("phase.cross_entity"):
+                    carry, (loss, _h, mask_rate) = self.semi_step(carry, xu)
                 f_u_acc.append(float(_host(loss)))
                 mask_acc.append(float(_host(mask_rate)))
         if pf is not None:
@@ -956,21 +982,21 @@ class SemiSFLSystem:
         # bottom is threaded through the phase unchanged — both ARE the
         # broadcast-time values.
         sparse = self.wire.topk_frac < 1.0
-        if self._use_sharded:
-            if sparse:
+        with span("fedavg"):
+            if self._use_sharded and sparse:
                 agg_bottom, agg_t_bottom = self._aggregate_sharded_topk(
                     bottoms, t_bottoms, state.params["bottom"],
                     teacher["bottom"])
+            elif self._use_sharded:
+                agg_bottom, agg_t_bottom = self._aggregate_sharded(
+                    bottoms, t_bottoms)
+            elif sparse:
+                agg_bottom, agg_t_bottom = self._aggregate_topk(
+                    bottoms, t_bottoms, state.params["bottom"],
+                    teacher["bottom"])
             else:
-                agg_bottom, agg_t_bottom = self._aggregate_sharded(bottoms,
-                                                                   t_bottoms)
-        elif sparse:
-            agg_bottom, agg_t_bottom = self._aggregate_topk(
-                bottoms, t_bottoms, state.params["bottom"],
-                teacher["bottom"])
-        else:
-            agg_bottom = self.aggregate(bottoms)
-            agg_t_bottom = self.aggregate(t_bottoms)
+                agg_bottom = self.aggregate(bottoms)
+                agg_t_bottom = self.aggregate(t_bottoms)
         params = {"bottom": agg_bottom, "top": top, "proj": proj}
         teacher = dict(teacher, bottom=agg_t_bottom)
         state = SemiSFLState(params=params, teacher=teacher, opt=state.opt,
@@ -993,6 +1019,7 @@ class SemiSFLSystem:
         return state, RoundMetrics(f_s=f_s, f_u=f_u, mask_rate=mask_rate,
                                    k_s=k_s)
 
+    @spanned("eval")
     def evaluate(self, state: SemiSFLState, test_x: np.ndarray,
                  test_y: np.ndarray, batch: int = 256,
                  use_teacher: bool = True) -> float:

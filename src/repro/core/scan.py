@@ -56,10 +56,24 @@ def default_unroll() -> Union[int, bool]:
     return n
 
 
+def _named_scan(step_fn: Callable, unroll: Union[int, bool, None],
+                name: str) -> Callable:
+    """``phase(carry, batches)``: ``lax.scan`` of ``step_fn`` over the
+    leading K axis, named ``name`` for jit."""
+    if unroll is None:
+        unroll = default_unroll()
+
+    def phase(carry: Carry, batches: Batch):
+        return jax.lax.scan(step_fn, carry, batches, unroll=unroll)
+
+    phase.__name__ = phase.__qualname__ = name
+    return phase
+
+
 def scan_phase(step_fn: Callable[[Carry, Batch], Tuple[Carry, Any]], *,
                donate_carry: bool = True,
                unroll: Union[int, bool, None] = None,
-               jit: bool = True
+               jit: bool = True, name: str = "phase"
                ) -> Callable[[Carry, Batch], Tuple[Carry, Any]]:
     """Build a compiled K-iteration phase from a single-iteration step.
 
@@ -72,14 +86,11 @@ def scan_phase(step_fn: Callable[[Carry, Batch], Tuple[Carry, Any]], *,
     ``donate_carry`` donates the input carry's buffers to the output so
     params/optimizer/queue update in place on accelerators (no-op where
     the backend does not support donation).  ``unroll`` is forwarded to
-    ``lax.scan`` (``None`` -> :func:`default_unroll`).
+    ``lax.scan`` (``None`` -> :func:`default_unroll`).  ``name`` names
+    the jitted program: its HLO module and device-trace events read
+    ``jit_<name>``.
     """
-    if unroll is None:
-        unroll = default_unroll()
-
-    def phase(carry: Carry, batches: Batch):
-        return jax.lax.scan(step_fn, carry, batches, unroll=unroll)
-
+    phase = _named_scan(step_fn, unroll, name)
     if not jit:
         return phase
     return jax.jit(phase, donate_argnums=(0,) if donate_carry else ())
@@ -89,7 +100,7 @@ def pinned_scan_phase(step_fn: Callable[[Carry, Batch], Tuple[Carry, Any]],
                       *, carry_shardings, out_shardings,
                       donate_carry: bool = True,
                       unroll: Union[int, bool, None] = None,
-                      jit: bool = True
+                      jit: bool = True, name: str = "phase"
                       ) -> Callable[[Carry, Batch], Tuple[Carry, Any]]:
     """:func:`scan_phase` with jit-level output-sharding pins and NO
     phase-level ``shard_map``.
@@ -110,12 +121,7 @@ def pinned_scan_phase(step_fn: Callable[[Carry, Batch], Tuple[Carry, Any]],
     tagging replicated metrics with degenerate data-axis shardings) and
     makes phase ``k+1`` see identically-committed inputs — same
     no-spurious-recompile argument as :func:`sharded_scan_phase`."""
-    if unroll is None:
-        unroll = default_unroll()
-
-    def phase(carry: Carry, batches: Batch):
-        return jax.lax.scan(step_fn, carry, batches, unroll=unroll)
-
+    phase = _named_scan(step_fn, unroll, name)
     if not jit:
         return phase
     return jax.jit(phase, donate_argnums=(0,) if donate_carry else (),
@@ -126,7 +132,7 @@ def sharded_scan_phase(step_fn: Callable[[Carry, Batch], Tuple[Carry, Any]],
                        *, mesh, carry_specs, batch_specs, out_specs,
                        donate_carry: bool = True,
                        unroll: Union[int, bool, None] = None,
-                       jit: bool = True
+                       jit: bool = True, name: str = "phase"
                        ) -> Callable[[Carry, Batch], Tuple[Carry, Any]]:
     """:func:`scan_phase` compiled under ``shard_map`` over ``mesh``.
 
@@ -146,12 +152,7 @@ def sharded_scan_phase(step_fn: Callable[[Carry, Batch], Tuple[Carry, Any]],
     check is off: replicated outputs are established via psum."""
     from repro.compat import shard_map
 
-    if unroll is None:
-        unroll = default_unroll()
-
-    def phase(carry: Carry, batches: Batch):
-        return jax.lax.scan(step_fn, carry, batches, unroll=unroll)
-
+    phase = _named_scan(step_fn, unroll, name)
     mapped = shard_map(phase, mesh=mesh,
                        in_specs=(carry_specs, batch_specs),
                        out_specs=(carry_specs, out_specs),

@@ -46,6 +46,7 @@ from typing import Any, Callable, Optional
 import numpy as np
 
 from repro.data.pipeline import Loader, stack_client_batches_many
+from repro.obs import span
 
 THREAD_NAME = "repro-prefetch"
 _SHUTDOWN = object()
@@ -125,27 +126,13 @@ class Prefetcher:
         that DIED without posting (thread crashed outside the build try,
         interpreter teardown killed the daemon) is detected immediately —
         the consumer must not sit out the full timeout on a pipeline that
-        can never produce."""
+        can never produce.  The blocking wait is the span
+        ``semisfl.prefetch.wait``."""
         t0 = time.perf_counter()
         deadline = None if timeout is None else t0 + timeout
         try:
-            while True:
-                try:
-                    tag, payload, err = self._res.get(timeout=0.1)
-                    break
-                except queue.Empty:
-                    if not self._thread.is_alive():
-                        self.close()
-                        raise PrefetchError(
-                            "prefetch worker died without posting a "
-                            "result") from None
-                    if deadline is not None and \
-                            time.perf_counter() >= deadline:
-                        self.close()
-                        raise PrefetchError(
-                            f"prefetch worker produced nothing within "
-                            f"{timeout}s (deadlocked or starved build?)"
-                        ) from None
+            with span("prefetch.wait"):
+                tag, payload, err = self._wait(timeout, deadline)
         finally:
             self.wait_s += time.perf_counter() - t0
         if err is not None:
@@ -153,6 +140,25 @@ class Prefetcher:
             raise PrefetchError(
                 f"prefetch build {tag!r} failed in the worker") from err
         return tag, payload
+
+    def _wait(self, timeout, deadline) -> tuple:
+        """Block until the worker posts ``(tag, payload, err)``."""
+        while True:
+            try:
+                return self._res.get(timeout=0.1)
+            except queue.Empty:
+                if not self._thread.is_alive():
+                    self.close()
+                    raise PrefetchError(
+                        "prefetch worker died without posting a "
+                        "result") from None
+                if deadline is not None and \
+                        time.perf_counter() >= deadline:
+                    self.close()
+                    raise PrefetchError(
+                        f"prefetch worker produced nothing within "
+                        f"{timeout}s (deadlocked or starved build?)"
+                    ) from None
 
     def close(self) -> None:
         """Idempotent shutdown: unblocks and joins the worker thread.
@@ -218,24 +224,19 @@ class RoundPrefetcher:
         self._spec: dict[str, tuple] = {}
         self.rounds = 0
         self.cancels = 0
-        self.inline_s = 0.0
 
-    # -- builders (worker thread on speculation, caller thread inline) --
+    # -- builders (worker thread on speculation, caller thread inline);
+    # each is a span on the thread that runs it
     def _build_sup(self, k: int):
-        xs, ys = self.labeled.next_many(k)
-        return self._sup_put(xs, ys) if self._sup_put else (xs, ys)
+        with span("batch.labeled"):
+            xs, ys = self.labeled.next_many(k)
+            return self._sup_put(xs, ys) if self._sup_put else (xs, ys)
 
     def _build_cli(self, active: list[int], k: int):
-        xs, _ = stack_client_batches_many(self.loaders, active, k,
-                                          shardings=self._cli_shardings)
-        return self._cli_put(xs) if self._cli_put else xs
-
-    def _inline(self, build, *args):
-        t0 = time.perf_counter()
-        try:
-            return build(*args)
-        finally:
-            self.inline_s += time.perf_counter() - t0
+        with span("batch.clients"):
+            xs, _ = stack_client_batches_many(self.loaders, active, k,
+                                              shardings=self._cli_shardings)
+            return self._cli_put(xs) if self._cli_put else xs
 
     # -- cancel/reshape protocol ---------------------------------------
     def _rollback(self, tag: str) -> None:
@@ -277,14 +278,14 @@ class RoundPrefetcher:
         rebuilds inline."""
         self.rounds += 1
         if "sup" not in self._spec:
-            return self._inline(self._build_sup, k)
+            return self._build_sup(k)
         payload = self._pop("sup")
         k_spec, snap = self._spec.pop("sup")
         if k_spec == k:
             return payload
         self.cancels += 1
         self.labeled.load_state_dict(snap)
-        return self._inline(self._build_sup, k)
+        return self._build_sup(k)
 
     def get_clients(self, active: list[int], k: int):
         """The ``(K, N, B, ...)`` client stacks for this round's active
@@ -292,7 +293,7 @@ class RoundPrefetcher:
         K match the actual request; otherwise restores the touched
         loaders and rebuilds inline."""
         if "cli" not in self._spec:
-            return self._inline(self._build_cli, list(active), k)
+            return self._build_cli(list(active), k)
         payload = self._pop("cli")
         act_spec, k_spec, snaps = self._spec.pop("cli")
         if act_spec == tuple(int(a) for a in active) and k_spec == k:
@@ -300,7 +301,7 @@ class RoundPrefetcher:
         self.cancels += 1
         for i, sd in snaps.items():
             self.loaders[i].load_state_dict(sd)
-        return self._inline(self._build_cli, list(active), k)
+        return self._build_cli(list(active), k)
 
     def speculate(self, k_s: int,
                   select_rng: Optional[np.random.RandomState]) -> None:
@@ -340,7 +341,6 @@ class RoundPrefetcher:
         b, w = self._pf.build_s, self._pf.wait_s
         return {"rounds": self.rounds, "cancels": self.cancels,
                 "spec_build_s": round(b, 6), "wait_s": round(w, 6),
-                "inline_s": round(self.inline_s, 6),
                 "overlap_frac": max(0.0, 1.0 - w / b) if b > 0 else 0.0}
 
     def close(self) -> None:

@@ -531,7 +531,8 @@ def make_sharded_train_phase(plan: StepPlan, mesh: Mesh,
         metrics_struct)
     return pinned_scan_phase(step, carry_shardings=shardings["state"],
                              out_shardings=out_shardings,
-                             donate_carry=donate_carry, unroll=unroll)
+                             donate_carry=donate_carry, unroll=unroll,
+                             name="train_phase")
 
 
 def make_scanned_train_phase(plan: StepPlan, dist: DistContext,
@@ -548,7 +549,7 @@ def make_scanned_train_phase(plan: StepPlan, dist: DistContext,
     the host syncs once per phase instead of once per step."""
     from repro.core.scan import scan_phase
     return scan_phase(make_train_step(plan, dist, lr, wire=wire),
-                      donate_carry=donate_carry)
+                      donate_carry=donate_carry, name="train_phase")
 
 
 def make_prefetched_train_phase(plan: StepPlan, dist: DistContext,

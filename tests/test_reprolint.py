@@ -131,6 +131,33 @@ def test_rl002_self_attr_indirection_and_scan_phase(tmp_path):
     assert _fires(f, rel, _line_of(tmp_path, rel, "float(x)"), "RL002")
 
 
+def test_rl002_flags_a_host_span_inside_a_hot_function(tmp_path):
+    _write(tmp_path, "src/repro/core/spans.py", """\
+        import jax
+        from repro.obs import span
+
+        def step(state, x):
+            with span("phase.step"):              # BAD: trace time only
+                y = state + x
+            with jax.profiler.TraceAnnotation("t"):   # BAD
+                y = y * 2
+            return y
+
+        step_j = jax.jit(step)
+
+        def drive(state, x):
+            with span("phase.step"):              # fine: on the host
+                return step_j(state, x)
+        """)
+    f = _lint(tmp_path, only=["RL002"])
+    rel = "src/repro/core/spans.py"
+    assert _fires(f, rel, _line_of(tmp_path, rel, "# BAD: trace"), "RL002")
+    assert _fires(f, rel, _line_of(tmp_path, rel, 'Annotation("t")'),
+                  "RL002")
+    assert not _fires(f, rel, _line_of(tmp_path, rel, "# fine: on the"),
+                      "RL002")
+
+
 def test_rl002_round_loop_requires_explicit_host_read(tmp_path):
     _write(tmp_path, "src/repro/core/loop.py", """\
         import numpy as np

@@ -14,6 +14,10 @@ the repo's ``scan_phase`` / ``sharded_scan_phase`` builders (directly,
 by name, through ``self.attr = fn`` indirection, or via a jit
 decorator), plus everything they call in the same module.
 
+A host span (``span``, ``TraceAnnotation``) inside a hot function is
+flagged too: it opens once while tracing and never while the program
+runs.
+
 Shape math is exempt: ``int(x.shape[0])``, ``float(len(xs))`` and
 friends never touch the device.
 """
@@ -31,6 +35,10 @@ _WRAPPERS = {"jax.jit", "jit", "jax.vmap", "vmap", "jax.grad", "grad",
              "jax.remat"}
 
 _CASTS = {"int", "float", "bool", "complex"}
+# host spans (``repro/obs.py``): inside a traced function they open once,
+# at trace time, and never again when the program runs
+_SPANS = {"span", "obs.span", "TraceAnnotation",
+          "jax.profiler.TraceAnnotation", "profiler.TraceAnnotation"}
 _NP_SYNCS = {"np.asarray", "np.array", "numpy.asarray", "numpy.array",
              "onp.asarray", "onp.array", "jax.device_get", "device_get"}
 
@@ -176,6 +184,12 @@ class HostSyncInHotPath(Rule):
                         module.relpath, n.lineno, self.code,
                         f"{cname}() inside hot function '{name}' — device "
                         "transfer in a traced/hot path; use jnp or hoist")
+                elif cname in _SPANS:
+                    yield Finding(
+                        module.relpath, n.lineno, self.code,
+                        f"host span {cname}() inside hot function '{name}' "
+                        "— it records the trace, not the run; open it "
+                        "around the call on the host")
                 elif isinstance(n.func, ast.Attribute) and \
                         n.func.attr == "item" and not n.args:
                     yield Finding(
