@@ -776,6 +776,13 @@ class SemiSFLSystem:
         logic, or run with ``scan_rounds=False``).  CPU ignores
         donation.
 
+        A scanned round reads the chip once, at its end: everything of
+        the round is dispatched first (supervised phase, selection,
+        broadcast, client stack, cross-entity phase, FedAvg), then both
+        phases' losses and mask rates come back in one transfer
+        (``_host_all``), then the controller updates.  The eager path
+        reads each step's outputs as it goes.
+
         Client selection draws from a host-side RandomState created once
         per run (``init_state`` seeds it; ``rng_np`` overrides it) — never
         from ``state.round``, which would force a device sync per round.
@@ -837,39 +844,30 @@ class SemiSFLSystem:
 
         # (1) supervised phase.  The LR schedule runs off the cumulative
         # step counter carried in the state — NOT round * (k_s_init + k_u),
-        # which skips steps once Eq. (10) shrinks K_s.
+        # which skips steps once Eq. (10) shrinks K_s.  A scanned phase's
+        # losses stay on the device until the round's one read at its end.
         if pf is not None:
             xs_d, ys_d = pf.get_supervised(k_s)   # already on device
-            if self.scan_rounds:
-                with span("phase.supervised"):
-                    state, losses_s = self.supervised_phase(state,
-                                                            (xs_d, ys_d))
-                f_s_acc = losses_s    # sync deferred past speculate()
-            else:
-                f_s_acc = []
-                for i in range(k_s):
+        if self.scan_rounds:
+            if pf is None:
+                with span("batch.labeled"):
+                    xs_d, ys_d = self._sup_put(*labeled.next_many(k_s))
+            with span("phase.supervised"):
+                state, f_s_acc = self.supervised_phase(state, (xs_d, ys_d))
+            del xs_d, ys_d        # its device buffers free once consumed
+        else:
+            f_s_acc = []
+            for i in range(k_s):
+                if pf is not None:
                     # static slice, not `xs_d[i]`: integer indexing
                     # commits the index constant (an implicit transfer
                     # the parity tests' guard rejects)
-                    with span("phase.supervised"):
-                        state, loss = self.supervised_step(
-                            state,
-                            (jax.lax.index_in_dim(xs_d, i, keepdims=False),
-                             jax.lax.index_in_dim(ys_d, i, keepdims=False)))
-                    f_s_acc.append(float(_host(loss)))
-        elif self.scan_rounds:
-            with span("batch.labeled"):
-                batch = self._sup_put(*labeled.next_many(k_s))
-            with span("phase.supervised"):
-                state, losses_s = self.supervised_phase(state, batch)
-            del batch             # its device buffers free once consumed
-            f_s_acc = _host(losses_s)             # one host sync per phase
-        else:
-            f_s_acc = []
-            for _ in range(k_s):
-                with span("batch.labeled"):
-                    x, y = labeled.next()
-                    batch = (jnp.asarray(x), jnp.asarray(y))
+                    batch = (jax.lax.index_in_dim(xs_d, i, keepdims=False),
+                             jax.lax.index_in_dim(ys_d, i, keepdims=False))
+                else:
+                    with span("batch.labeled"):
+                        x, y = labeled.next()
+                        batch = (jnp.asarray(x), jnp.asarray(y))
                 with span("phase.supervised"):
                     state, loss = self.supervised_step(state, batch)
                 f_s_acc.append(float(_host(loss)))
@@ -917,51 +915,33 @@ class SemiSFLSystem:
                  state.step)
         if k_u == 0:
             f_u_acc, mask_acc = np.zeros((0,)), np.zeros((0,))
-        elif pf is not None:
-            xus = pf.get_clients(stack_active, k_u)  # on device/shards
-            if self._use_sharded:
-                with span("phase.cross_entity"):
-                    carry, (losses_u, _h, masks) = self.semi_phase_sharded(
-                        carry, xus)
-            elif self.scan_rounds:
-                with span("phase.cross_entity"):
-                    carry, (losses_u, _h, masks) = self.semi_phase(carry,
-                                                                   xus)
+        elif self.scan_rounds:        # the client-sharded executor included
+            if pf is not None:
+                xus = pf.get_clients(stack_active, k_u)  # on device/shards
             else:
-                losses_u, masks = [], []
-                for i in range(k_u):
-                    with span("phase.cross_entity"):
-                        carry, (loss, _h, mask_rate) = self.semi_step(
-                            carry,
-                            jax.lax.index_in_dim(xus, i, keepdims=False))
-                    losses_u.append(float(_host(loss)))
-                    masks.append(float(_host(mask_rate)))
-            f_u_acc, mask_acc = losses_u, masks   # sync deferred
-        elif self._use_sharded:
-            with span("batch.clients"):
-                xus, _ = stack_client_batches_many(
-                    client_loaders_, stack_active, k_u,
-                    shardings=self._stack_shardings)
-            with span("phase.cross_entity"):
-                carry, (losses_u, _h, masks) = self.semi_phase_sharded(
-                    carry, xus)
-            f_u_acc, mask_acc = _host(losses_u), _host(masks)
-        elif self.scan_rounds:
-            with span("batch.clients"):
-                xus, _ = stack_client_batches_many(client_loaders_,
-                                                   stack_active, k_u)
-                xus = jnp.asarray(xus)
-            with span("phase.cross_entity"):
-                carry, (losses_u, _h, masks) = self.semi_phase(carry, xus)
-            del xus
-            f_u_acc, mask_acc = _host(losses_u), _host(masks)
-        else:
-            f_u_acc, mask_acc = [], []
-            for _ in range(k_u):
                 with span("batch.clients"):
-                    xu, _ = stack_client_batches(client_loaders_,
-                                                 stack_active)
-                    xu = jnp.asarray(xu)
+                    sh = self._stack_shardings if self._use_sharded else None
+                    xus, _ = stack_client_batches_many(
+                        client_loaders_, stack_active, k_u, shardings=sh)
+                    if sh is None:
+                        xus = jnp.asarray(xus)
+            phase = (self.semi_phase_sharded if self._use_sharded
+                     else self.semi_phase)
+            with span("phase.cross_entity"):
+                carry, (f_u_acc, _h, mask_acc) = phase(carry, xus)
+            del xus
+        else:
+            if pf is not None:
+                xus = pf.get_clients(stack_active, k_u)  # on device
+            f_u_acc, mask_acc = [], []
+            for i in range(k_u):
+                if pf is not None:
+                    xu = jax.lax.index_in_dim(xus, i, keepdims=False)
+                else:
+                    with span("batch.clients"):
+                        xu, _ = stack_client_batches(client_loaders_,
+                                                     stack_active)
+                        xu = jnp.asarray(xu)
                 with span("phase.cross_entity"):
                     carry, (loss, _h, mask_rate) = self.semi_step(carry, xu)
                 f_u_acc.append(float(_host(loss)))
@@ -1004,14 +984,14 @@ class SemiSFLSystem:
                              round=state.round + self._one_i32,
                              step=step)
 
-        # metric sync point: _host (np.asarray + the replicated-output
-        # read multi-process needs) first so the deferred prefetch-path
-        # device arrays reduce with numpy's host reduction order (bit-equal
-        # to the synchronous path), not jnp's on-device .mean().  Every
-        # process syncs the same replicated values, so the controller —
-        # and with it the next round's K_s — stays in lockstep fleet-wide.
-        f_s_acc, mask_acc = _host(f_s_acc), _host(mask_acc)
-        f_u_acc = _host(f_u_acc)
+        # metric sync point, after everything of the round is dispatched:
+        # the round's one device-to-host read (the eager path's values are
+        # on the host already).  Read first, then reduce with numpy, so the
+        # scanned executors' device arrays reduce in numpy's host order,
+        # not with jnp's on-device .mean().  Every process reads the same
+        # replicated values, so the controller — and with it the next
+        # round's K_s — stays in lockstep fleet-wide.
+        f_s_acc, f_u_acc, mask_acc = _host_all(f_s_acc, f_u_acc, mask_acc)
         f_s = float(np.mean(f_s_acc)) if len(f_s_acc) else 0.0
         f_u = float(np.mean(f_u_acc)) if len(f_u_acc) else 0.0
         controller.update(f_s, f_u)
@@ -1042,3 +1022,22 @@ class SemiSFLSystem:
 def make_controller(cfg: ArchConfig, n_labeled: int, n_total: int
                     ) -> FreqController:
     return FreqController(cfg.semisfl, n_labeled, n_total)
+
+
+def _host_all(*xs) -> list[np.ndarray]:
+    """``_host`` of each value in one read, the span ``semisfl.sync``:
+    every device-to-host transfer is issued before any is waited on
+    (``jax.device_get`` of a list).  A value this process cannot fully
+    address goes through ``distributed.fetch``, as in ``_host``, in
+    argument order on every process.  Host values need no read: with no
+    device value among them there is no span."""
+    if not any(isinstance(x, jax.Array) for x in xs):
+        return [np.asarray(x) for x in xs]
+    remote = [isinstance(x, jax.Array) and not x.is_fully_addressable
+              for x in xs]
+    with span("sync"):
+        from repro.launch.distributed import fetch
+        local = iter(jax.device_get(
+            [x for x, r in zip(xs, remote) if not r]))
+        return [fetch(x) if r else np.asarray(next(local))
+                for x, r in zip(xs, remote)]

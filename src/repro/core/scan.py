@@ -9,8 +9,8 @@ per-iteration ``step_fn(carry, batch) -> (carry, out)`` into a jitted
 
 that ``lax.scan``s over the leading ``K`` axis of every leaf in
 ``batches``, carrying the training state on-device with buffer donation.
-The host syncs once per phase (when it reads ``stacked_outs``) instead of
-once per step.
+The host reads ``stacked_outs`` once, not once per step; the engine
+reads both phases' outputs in one transfer at the round's end.
 
 Both the classification engine (``core/engine.py`` supervised + cross-
 entity phases) and the LM-task train step (``launch/steps.py``) build

@@ -79,4 +79,10 @@ def apply_projection_head(p: Params, cfg: ArchConfig, feats: Array) -> Array:
         x = x @ p["w1"]
     if "w2" in p:
         x = jax.nn.relu(x) @ p["w2"]
-    return x / jnp.maximum(jnp.linalg.norm(x, axis=-1, keepdims=True), 1e-6)
+    # the norm's gradient is 0/0 at a zero vector (a sample whose hidden
+    # projection is all zero), which made every projection weight NaN;
+    # selecting around the sqrt keeps the norm and its gradient finite
+    nz = jnp.any(x != 0, axis=-1, keepdims=True)
+    norm = jnp.where(nz, jnp.linalg.norm(jnp.where(nz, x, 1.0), axis=-1,
+                                         keepdims=True), 0.0)
+    return x / jnp.maximum(norm, 1e-6)

@@ -113,3 +113,40 @@ def test_supervised_contrastive_excludes_self(rng):
     g = jax.grad(lambda zz: losses.supervised_contrastive_loss(
         zz, labels, qz, jnp.zeros(3, jnp.int32), qvalid, 0.5))(z)
     assert np.isfinite(np.asarray(g)).all()
+
+
+def test_projection_head_gradient_finite_at_a_zero_vector(rng):
+    """A sample whose hidden projection is all zero (ReLU-dead, or zero
+    pooled features) projects to z = 0.  The l2 norm's gradient there is
+    0/0; left unguarded it turned every projection weight NaN in one step
+    (paper-cnn on a TPU v5e, near round 990 of a cnn-default window).
+    Nonzero rows keep the plain formula's value and gradient bit for bit."""
+    from repro.configs import smoke_config
+    from repro.core.split import apply_projection_head, init_projection_head
+    cfg = smoke_config("paper-cnn")
+    p = init_projection_head(jax.random.PRNGKey(0), cfg)
+    feats = jnp.asarray(rng.rand(8, p["w1"].shape[0]), jnp.float32)
+    weights = jnp.arange(cfg.semisfl.proj_dim, dtype=jnp.float32)
+
+    def plain(p, f):
+        x = jax.nn.relu(f @ p["w1"]) @ p["w2"]
+        return x / jnp.maximum(jnp.linalg.norm(x, axis=-1, keepdims=True),
+                               1e-6)
+
+    def loss(head, p, f):
+        return (head(p, f) * weights).sum()
+
+    ours = lambda p, f: apply_projection_head(p, cfg, f)
+    np.testing.assert_array_equal(ours(p, feats), plain(p, feats))
+    for a, b in zip(jax.tree.leaves(jax.grad(loss, 1)(ours, p, feats)),
+                    jax.tree.leaves(jax.grad(loss, 1)(plain, p, feats))):
+        np.testing.assert_array_equal(a, b)
+
+    dead = feats.at[3].set(0.0)
+    assert not np.asarray(ours(p, dead)[3]).any()
+    grads = jax.grad(loss, 1)(ours, p, dead)
+    assert all(np.isfinite(np.asarray(g)).all()
+               for g in jax.tree.leaves(grads))
+    # the plain formula is the fault this guards against
+    assert not all(np.isfinite(np.asarray(g)).all()
+                   for g in jax.tree.leaves(jax.grad(loss, 1)(plain, p, dead)))

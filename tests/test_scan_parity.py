@@ -11,8 +11,10 @@ from dataclasses import replace
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.configs import smoke_config
+from repro.core import engine
 from repro.core.engine import SemiSFLSystem, make_controller
 from repro.core.scan import scan_phase
 from repro.data import (Loader, client_loaders, make_image_dataset,
@@ -111,6 +113,82 @@ def test_scanned_round_same_when_ks_adapts(no_implicit_transfers):
     assert _max_abs_diff(results[False].params, results[True].params) < 1e-5
     # step counter is cumulative over the ACTUAL k_s values, no drift
     assert int(_get(results[True].step)) == (3 + 2) + (2 + 2)
+
+
+@pytest.mark.parametrize(
+    "scan_rounds,prefetch", [(True, False), (True, True), (False, False),
+                             (False, True)],
+    ids=["scanned", "scanned-prefetched", "eager", "eager-prefetched"])
+def test_round_reads_the_chip_once(scan_rounds, prefetch, monkeypatch,
+                                   no_implicit_transfers):
+    """A scanned round makes one device-to-host read, at its end, on
+    both the synchronous and the prefetched executor; the eager path
+    reads each step's losses, K_s + 2 K_u reads a round.  Reads are
+    counted as ``semisfl.sync`` spans of the driver thread."""
+    names = []
+    real_span = engine.span
+
+    def recording_span(name, **stats):
+        names.append(name)
+        return real_span(name, **stats)
+
+    monkeypatch.setattr(engine, "span", recording_span)
+    cfg = _tiny_cfg()
+    with jax.transfer_guard("allow"):   # setup, see _run
+        train, _, lab, cls = _rig(cfg)
+        sys_ = SemiSFLSystem(cfg, n_clients_per_round=3,
+                             scan_rounds=scan_rounds, prefetch=prefetch)
+        state = sys_.init_state(0)
+        ctrl = make_controller(cfg, 40, len(train.y))
+    k_u = cfg.semisfl.k_u
+    for k_s in (3, 2):                  # the second round adapts K_s
+        ctrl.k_s = k_s
+        names.clear()
+        state, _ = sys_.run_round(state, lab, cls, ctrl)
+        reads = names.count("sync")
+        assert reads == (1 if scan_rounds else k_s + 2 * k_u), names
+        if scan_rounds:                 # the read comes after every dispatch
+            assert names[-1] == "sync", names
+    sys_.close()
+
+
+def test_controller_adaptation_same_on_every_executor(no_implicit_transfers):
+    """Eq. (10) shrinks K_s on its own (observation period and window of
+    one round, K_min 1): the controller is fed the round's read-back
+    losses, so the scanned executors, synchronous and prefetched, give
+    the same RoundMetrics and state bit for bit, and the eager path the
+    same K_s history with numerically equal metrics."""
+    cfg = _tiny_cfg()
+    cfg = replace(cfg, semisfl=replace(cfg.semisfl, observation_period=1,
+                                       adaptation_window=1, beta=1.0))
+    runs = {}
+    for scan, prefetch in ((True, False), (True, True), (False, False)):
+        with jax.transfer_guard("allow"):   # setup, see _run
+            train, _, lab, cls = _rig(cfg)
+            sys_ = SemiSFLSystem(cfg, n_clients_per_round=3,
+                                 scan_rounds=scan, prefetch=prefetch)
+            state = sys_.init_state(0)
+            ctrl = make_controller(cfg, 40, len(train.y))
+        metrics = []
+        for _ in range(4):
+            state, m = sys_.run_round(state, lab, cls, ctrl)
+            metrics.append((m.f_s, m.f_u, m.mask_rate, m.k_s))
+        sys_.close()
+        runs[scan, prefetch] = state, metrics, list(ctrl.history)
+    s_sync, m_sync, k_sync = runs[True, False]
+    s_pf, m_pf, k_pf = runs[True, True]
+    s_eager, m_eager, k_eager = runs[False, False]
+    assert len(set(k_sync)) > 1, k_sync             # K_s adapted
+    assert k_sync == k_pf == k_eager
+    assert m_sync == m_pf                            # floats, exact
+    same = jax.tree.map(
+        lambda a, b: bool(np.array_equal(_get(a), _get(b))),
+        (s_sync.params, s_sync.teacher, s_sync.queue),
+        (s_pf.params, s_pf.teacher, s_pf.queue))
+    assert all(jax.tree.leaves(same)), same
+    for a, b in zip(m_eager, m_sync):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+    assert _max_abs_diff(s_eager.params, s_sync.params) < 1e-5
 
 
 def test_scan_phase_builder_matches_python_loop():
