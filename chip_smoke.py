@@ -6,8 +6,9 @@
 
 One chip: the Mosaic kernels are compared with their jnp references at the
 round's own shapes, then ``launch/train.py::run_training`` trains
-``paper-vgg16`` at its published widths (144x144 inputs, 13 convs,
-FC-4096 x 2, queue 2048) for 3 aggregation rounds with the fp32 wire and 2
+``vgg16-image100``, VGG16 as published (144x144 inputs, 13 convs with
+five max-pools, a 7x7 average pool, FC-4096 x 2, queue 2048), for 3
+aggregation rounds with the fp32 wire and 2
 rounds with the int8 wire.  Each run's cross-entity phase must contain the
 Mosaic custom call of its kernels, and every round's metrics and the final
 accuracy must be finite.
@@ -35,10 +36,10 @@ from pathlib import Path
 ROOT = Path(__file__).resolve().parent
 sys.path.insert(0, str(ROOT / "src"))
 
-ARCH = "paper-vgg16"
+ARCH = "vgg16-image100"
 # the four-chip phase checks the client-sharded mechanism, which is the
 # same for every CNN; paper-cnn at its published widths compiles in
-# seconds where paper-vgg16 takes minutes per program
+# seconds where VGG16 takes minutes per program
 FOUR_CHIP_ARCH = "paper-cnn"
 TEMPERATURE = 0.1
 # kernel vs reference at the round's shapes: both run float32 matmuls at

@@ -162,6 +162,12 @@ class ArchConfig:
     # only in train-mode forwards that supply per-sample dropout keys —
     # eval-mode forwards are deterministic by construction.
     cnn_dropout: float = 0.0
+    # 2x2 max-pools after these convs, counted from 1; () pools where the
+    # width changes and after the last conv
+    cnn_pool_after: Tuple[int, ...] = ()
+    # adaptive average pool of the last conv's grid to this side before
+    # the FC stack (torchvision's AdaptiveAvgPool2d); 0: none
+    cnn_pool_to: int = 0
     image_size: int = 32
     num_classes: int = 0                # classification task head (paper task)
 
@@ -186,16 +192,32 @@ class ArchConfig:
         # AlexNet@5, VGG13@10, VGG16@13): its top still holds the FC stack
         return min(s, self.num_layers - (self.arch_type != "cnn"))
 
+    @property
+    def cnn_pooled(self) -> Tuple[bool, ...]:
+        """Per conv, whether a 2x2 max-pool follows it."""
+        ch = self.cnn_channels
+        if self.cnn_pool_after:
+            return tuple(i + 1 in self.cnn_pool_after for i in range(len(ch)))
+        return tuple(i == len(ch) - 1 or ch[i + 1] != c
+                     for i, c in enumerate(ch))
+
+    @property
+    def cnn_fc_in(self) -> int:
+        """Inputs of the first FC layer: the last conv's grid, max-pooled,
+        then average-pooled to ``cnn_pool_to`` where that is set."""
+        hw = self.cnn_pool_to or \
+            self.image_size // 2 ** sum(self.cnn_pooled)
+        return hw * hw * self.cnn_channels[-1]
+
     def param_count(self) -> int:
         """Analytic total parameter count (used by roofline + comm model)."""
         d, ff, V, L = self.d_model, self.d_ff, self.vocab_size, self.num_layers
         if self.arch_type == "cnn":
-            total, cin, hw = 0, 3, self.image_size
+            total, cin = 0, 3
             for cout in self.cnn_channels:
                 total += cin * cout * 9 + cout
                 cin = cout
-                hw //= 2
-            feat = cin * hw * hw
+            feat = self.cnn_fc_in
             for fc in self.cnn_fc:
                 total += feat * fc + fc
                 feat = fc
@@ -320,10 +342,12 @@ _MODULES = {
     "paper-alexnet": "repro.configs.paper_models",
     "paper-vgg13": "repro.configs.paper_models",
     "paper-vgg16": "repro.configs.paper_models",
+    "vgg16-image100": "repro.configs.paper_models",
 }
 
-ASSIGNED_ARCHS = [k for k in _MODULES if not k.startswith("paper-")]
-PAPER_ARCHS = [k for k in _MODULES if k.startswith("paper-")]
+PAPER_ARCHS = [k for k, m in _MODULES.items()
+               if m == "repro.configs.paper_models"]
+ASSIGNED_ARCHS = [k for k in _MODULES if k not in PAPER_ARCHS]
 
 
 def register(cfg: ArchConfig) -> ArchConfig:

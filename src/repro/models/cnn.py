@@ -2,8 +2,10 @@
 VGG16) as split CNN classifiers.
 
 Structure: a stack of 3x3 conv+ReLU layers (``cfg.cnn_channels``) with 2x2
-max-pool at channel-width changes and after the last conv, followed by the
-FC stack (``cfg.cnn_fc``) and the classifier.  The SFL split index counts
+max-pool after the convs ``cfg.cnn_pool_after`` names (by default at
+channel-width changes and after the last conv), an optional adaptive
+average pool to ``cfg.cnn_pool_to`` (torchvision's ``AdaptiveAvgPool2d``),
+the FC stack (``cfg.cnn_fc``) and the classifier.  The SFL split index counts
 conv layers: ``bottom`` = convs[:split] (client), ``top`` = the rest
 (server) — matching the paper's choices (CNN@2, AlexNet@5, VGG13@10,
 VGG16@13) where clients hold the convolutional feature extractor and the
@@ -17,17 +19,9 @@ import jax.numpy as jnp
 from repro.configs.base import ArchConfig
 from repro.models.common import Params, dense_init, zeros
 from repro.models.moe import DistContext
+from repro.obs import scope
 
 Array = jax.Array
-
-
-def _pool_at(channels) -> list[bool]:
-    out = []
-    for i, c in enumerate(channels):
-        last = i == len(channels) - 1
-        change = (not last) and channels[i + 1] != c
-        out.append(last or change)
-    return out
 
 
 def _conv_init(key, cin, cout, dtype):
@@ -48,11 +42,24 @@ def _maxpool(x):
                                  (1, 2, 2, 1), "VALID")
 
 
+def adaptive_avg_pool(x: Array, n_out: int) -> Array:
+    """(B, H, W, C) -> (B, n_out, n_out, C) by torchvision's bins: output
+    cell ``i`` averages input rows ``floor(i n / n_out)`` up to
+    ``ceil((i + 1) n / n_out)``, end excluded, along each axis."""
+    def along(x, axis):
+        n = x.shape[axis]
+        cells = [jax.lax.slice_in_dim(x, i * n // n_out,
+                                      -(-(i + 1) * n // n_out), axis=axis)
+                 .mean(axis=axis) for i in range(n_out)]
+        return jnp.stack(cells, axis=axis)
+    return along(along(x, 1), 2)
+
+
 class CNNModel:
     def __init__(self, cfg: ArchConfig):
         self.cfg = cfg
         self.split = min(cfg.split_layer, len(cfg.cnn_channels))
-        self.pool_at = _pool_at(cfg.cnn_channels)
+        self.pool_at = list(cfg.cnn_pooled)
 
     # -- shape bookkeeping ---------------------------------------------------
     def _feat_shape(self, upto: int):
@@ -74,8 +81,7 @@ class CNNModel:
             convs.append(_conv_init(keys[i], cin, cout, dt))
             cin = cout
         bottom = {"convs": convs[: self.split]}
-        hw, c = self._feat_shape(n)
-        feat = hw * hw * c
+        feat = cfg.cnn_fc_in
         fcs = []
         for j, width in enumerate(cfg.cnn_fc):
             fcs.append({"w": dense_init(keys[n + j], feat, width, dt),
@@ -96,10 +102,11 @@ class CNNModel:
                      mode: str = "train", cache=None,
                      dist: DistContext = DistContext()):
         x = batch_inputs["images"].astype(jnp.dtype(self.cfg.dtype))
-        for i, p in enumerate(params["convs"]):
-            x = _conv_apply(p, x)
-            if self.pool_at[i]:
-                x = _maxpool(x)
+        with scope("model.conv"):
+            for i, p in enumerate(params["convs"]):
+                x = _conv_apply(p, x)
+                if self.pool_at[i]:
+                    x = _maxpool(x)
         return x, None, {"aux_loss": jnp.zeros((), jnp.float32)}
 
     def _dropout(self, x: Array, keys: Array, layer: int) -> Array:
@@ -124,20 +131,22 @@ class CNNModel:
                   mode: str = "train", cache=None,
                   dist: DistContext = DistContext()):
         x = features
-        for i, p in enumerate(params["convs"]):
-            j = self.split + i
-            x = _conv_apply(p, x)
-            if self.pool_at[j]:
-                x = _maxpool(x)
-        b = x.shape[0]
-        x = x.reshape(b, -1)
+        with scope("model.conv"):
+            for i, p in enumerate(params["convs"]):
+                x = _conv_apply(p, x)
+                if self.pool_at[self.split + i]:
+                    x = _maxpool(x)
         drop_keys = extras.get("dropout_keys")
         use_dropout = (mode == "train" and self.cfg.cnn_dropout > 0.0
                        and drop_keys is not None)
-        for li, p in enumerate(params["fcs"]):
-            x = jax.nn.relu(x @ p["w"] + p["b"])
-            if use_dropout:
-                x = self._dropout(x, drop_keys, li)
-        logits = x @ params["cls"]["w"] + params["cls"]["b"]
+        with scope("model.fc"):
+            if self.cfg.cnn_pool_to:
+                x = adaptive_avg_pool(x, self.cfg.cnn_pool_to)
+            x = x.reshape(x.shape[0], -1)
+            for li, p in enumerate(params["fcs"]):
+                x = jax.nn.relu(x @ p["w"] + p["b"])
+                if use_dropout:
+                    x = self._dropout(x, drop_keys, li)
+            logits = x @ params["cls"]["w"] + params["cls"]["b"]
         return ({"logits": logits, "hidden": x,
                  "aux_loss": extras.get("aux_loss", 0.0)}, None)

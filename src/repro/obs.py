@@ -16,6 +16,17 @@ or scanned function.  ``bench/spans.py`` reads them back from a trace.
     fedavg                FedAvg of the client bottoms
     sync                  a device-to-host read
     eval                  SemiSFLSystem.evaluate, the whole call
+
+A scope is the other kind of name: ``jax.named_scope("semisfl.<name>")``
+around model code inside a jitted or scanned function.  It runs only
+while tracing and leaves ``semisfl.<name>`` in the ``op_name`` metadata
+of every device operation the code lowers to, backward passes and
+vmapped clients included (``transpose(jvp(semisfl.model.fc))/...``), so
+a device trace can sum those operations' time.  It is not a span: it
+has no host event.
+
+    model.conv            the conv stacks, max-pools included
+    model.fc              average pool, FC stack and classifier
 """
 from __future__ import annotations
 
@@ -41,3 +52,10 @@ def spanned(name: str):
                 return fn(*args, **kwargs)
         return inner
     return wrap
+
+
+def scope(name: str):
+    """Context manager naming the device operations traced inside it
+    ``semisfl.<name>``; for use inside jitted or scanned functions."""
+    import jax
+    return jax.named_scope(PREFIX + name)

@@ -158,6 +158,28 @@ def test_rl002_flags_a_host_span_inside_a_hot_function(tmp_path):
                       "RL002")
 
 
+@pytest.mark.parametrize("call,fires", [("obs.span", True),
+                                        ("obs.scope", False)])
+def test_rl002_flags_obs_span_but_not_obs_scope_in_a_hot_function(
+        tmp_path, call, fires):
+    """A span opens on the host; a scope (``jax.named_scope``) names the
+    traced operations and belongs inside the jitted function."""
+    _write(tmp_path, "src/repro/models/scoped.py", f"""\
+        import jax
+        from repro import obs
+
+        def step(x):
+            with {call}("model.conv"):       # the call under test
+                return x * 2
+
+        step_j = jax.jit(step)
+        """)
+    f = _lint(tmp_path, only=["RL002"])
+    rel = "src/repro/models/scoped.py"
+    assert _fires(f, rel, _line_of(tmp_path, rel, "# the call under"),
+                  "RL002") is fires
+
+
 def test_rl002_round_loop_requires_explicit_host_read(tmp_path):
     _write(tmp_path, "src/repro/core/loop.py", """\
         import numpy as np
