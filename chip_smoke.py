@@ -265,8 +265,7 @@ def four_chips() -> None:
                   f"round {a['round']} {key}: {a[key]} vs {b[key]}")
 
     # the client axis is spread over the four devices, one block each
-    bottoms, _ = sys_sh._broadcast_sharded(s_sh.params["bottom"],
-                                           s_sh.teacher["bottom"])
+    bottoms, _ = sys_sh.broadcast(s_sh)
     devices = set(mesh.devices.ravel())
     for leaf in jax.tree.leaves(bottoms):
         shards = leaf.addressable_shards

@@ -25,10 +25,13 @@ Two executors drive the cross-entity phase:
   * client-sharded (``mesh=`` + ``REPRO_SHARD_CLIENTS``): the same scan
     compiled under ``shard_map`` with the client axis sharded over the
     mesh's data axes.  Per-client bottom updates run collective-free on
-    their shard; the top/proj gradient (Eq. (7)) is one psum; broadcast /
-    FedAvg are in-program (GSPMD all-reduce) instead of host-side
-    tree.maps.  Both executors are numerically equivalent (see
-    tests/test_shard_clients.py)."""
+    their shard; the top/proj gradient (Eq. (7)) is one psum; FedAvg is
+    one GSPMD all-reduce.  Both executors are numerically equivalent (see
+    tests/test_shard_clients.py).
+
+Both run step (2), the broadcast, and step (5), FedAvg, as one compiled
+program each, ``jit_broadcast`` and ``jit_fedavg``; the sharded executor
+pins their outputs to its mesh."""
 from __future__ import annotations
 
 import functools
@@ -576,8 +579,31 @@ class SemiSFLSystem:
             return new_carry, (loss, h, mask_rate)
 
         self.semi_step_sharded = self._at_config_precision(semi_step_sharded)
+
+        # ---------------- steps (2) and (5): broadcast and FedAvg ---------
+        # One compiled program each, for both executors; their HLO modules
+        # and device-trace events read jit_broadcast and jit_fedavg.  The
+        # client-sharded executor pins the outputs to its mesh: each
+        # client's replica lands on its shard, and FedAvg compiles to one
+        # all-reduce over the data axes.  A program's outputs are fresh
+        # buffers, so the cross-entity phase may donate the stacks.
+        n_active = self.n_active
+
+        def broadcast(global_bottom, teacher_bottom):
+            stack = lambda t: jnp.broadcast_to(t, (n_active,) + t.shape)
+            return (jax.tree.map(stack, global_bottom),
+                    jax.tree.map(stack, teacher_bottom))
+
+        def fedavg(bottoms, t_bottoms):
+            return _client_mean(bottoms), _client_mean(t_bottoms)
+
+        pin_stacked, pin_replicated = {}, {}
         if self._use_sharded:
-            self._build_sharded_exec()
+            stacked_sh, rep_sh = self._build_sharded_exec()
+            pin_stacked = {"out_shardings": (stacked_sh, stacked_sh)}
+            pin_replicated = {"out_shardings": (rep_sh, rep_sh)}
+        self._broadcast = jax.jit(broadcast, **pin_stacked)
+        self._aggregate = jax.jit(fedavg, **pin_replicated)
 
         # ---------------- evaluation (teacher model, Section V-B) ---------
         def eval_batch(params, x, y):
@@ -587,8 +613,10 @@ class SemiSFLSystem:
         self.eval_batch = jax.jit(self._at_config_precision(eval_batch))
 
     def _build_sharded_exec(self):
-        """Compile the client-sharded executor: the shard_map'd scan phase
-        plus in-program broadcast (step (2)) and FedAvg (step (5))."""
+        """Compile the client-sharded executor's shard_map'd scan phase;
+        returns the shardings of a client stack and of a replicated
+        bottom, which pin the broadcast (step (2)) and FedAvg (step (5))
+        programs to the mesh."""
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from repro.sharding.specs import (client_batch_pspec,
@@ -654,25 +682,6 @@ class SemiSFLSystem:
         stacked_sh = tree_shardings(mesh, leading_axis_pspecs(abs_stack,
                                                               axes))
         rep_sh = tree_shardings(mesh, replicated_pspecs(abs_bottom))
-        n_active = self.n_active
-
-        def _broadcast(global_bottom, teacher_bottom):
-            stack = lambda t: jnp.broadcast_to(t, (n_active,) + t.shape)
-            return (jax.tree.map(stack, global_bottom),
-                    jax.tree.map(stack, teacher_bottom))
-
-        def _aggregate(bottoms, t_bottoms):
-            mean = lambda t: t.mean(axis=0)
-            return (jax.tree.map(mean, bottoms),
-                    jax.tree.map(mean, t_bottoms))
-
-        # in-program collectives: broadcast materializes each client's
-        # replica directly on its shard; FedAvg compiles to one all-reduce
-        # over the data axes (GSPMD) instead of a host-side tree.map
-        self._broadcast_sharded = jax.jit(
-            _broadcast, out_shardings=(stacked_sh, stacked_sh))
-        self._aggregate_sharded = jax.jit(
-            _aggregate, out_shardings=(rep_sh, rep_sh))
         if self.wire.topk_frac < 1.0:
             frac = self.wire.topk_frac
 
@@ -685,6 +694,7 @@ class SemiSFLSystem:
 
             self._aggregate_sharded_topk = jax.jit(
                 _aggregate_topk, out_shardings=(rep_sh, rep_sh))
+        return stacked_sh, rep_sh
 
     # ------------------------------------------------------------------
     # round driver
@@ -749,17 +759,15 @@ class SemiSFLSystem:
             pass
 
     def broadcast(self, state: SemiSFLState):
-        """Step (2): replicate global + teacher bottoms to active clients."""
-        stack = lambda t: jnp.broadcast_to(
-            t, (self.n_active,) + t.shape).copy()
-        bottoms = jax.tree.map(stack, state.params["bottom"])
-        t_bottoms = jax.tree.map(stack, state.teacher["bottom"])
-        return bottoms, t_bottoms
+        """Step (2): replicate global + teacher bottoms to active clients
+        (the compiled ``jit_broadcast`` program)."""
+        return self._broadcast(state.params["bottom"],
+                               state.teacher["bottom"])
 
     @staticmethod
     def aggregate(client_bottoms):
-        """Step (5): FedAvg over the client axis."""
-        return jax.tree.map(lambda t: t.mean(axis=0), client_bottoms)
+        """Step (5): FedAvg over the client axis of one stacked tree."""
+        return _client_mean_jit(client_bottoms)
 
     @spanned("round")
     def run_round(self, state: SemiSFLState, labeled: Loader,
@@ -903,11 +911,7 @@ class SemiSFLSystem:
                         "(select_pod_blocked)")
             stack_active = pc.local_indices(active)
         with span("broadcast"):
-            if self._use_sharded:
-                bottoms, t_bottoms = self._broadcast_sharded(
-                    state.params["bottom"], state.teacher["bottom"])
-            else:
-                bottoms, t_bottoms = self.broadcast(state)
+            bottoms, t_bottoms = self.broadcast(state)
 
         # (3)-(4) cross-entity phase
         carry = (bottoms, t_bottoms, state.params["top"],
@@ -967,16 +971,13 @@ class SemiSFLSystem:
                 agg_bottom, agg_t_bottom = self._aggregate_sharded_topk(
                     bottoms, t_bottoms, state.params["bottom"],
                     teacher["bottom"])
-            elif self._use_sharded:
-                agg_bottom, agg_t_bottom = self._aggregate_sharded(
-                    bottoms, t_bottoms)
             elif sparse:
                 agg_bottom, agg_t_bottom = self._aggregate_topk(
                     bottoms, t_bottoms, state.params["bottom"],
                     teacher["bottom"])
             else:
-                agg_bottom = self.aggregate(bottoms)
-                agg_t_bottom = self.aggregate(t_bottoms)
+                agg_bottom, agg_t_bottom = self._aggregate(bottoms,
+                                                           t_bottoms)
         params = {"bottom": agg_bottom, "top": top, "proj": proj}
         teacher = dict(teacher, bottom=agg_t_bottom)
         state = SemiSFLState(params=params, teacher=teacher, opt=state.opt,
@@ -1017,6 +1018,15 @@ class SemiSFLSystem:
                 xb, yb = jnp.asarray(xb), jnp.asarray(yb)
             correct += float(_host(self.eval_batch(params, xb, yb)))
         return correct / len(test_y)
+
+
+def _client_mean(tree):
+    """FedAvg of one client-stacked tree: the mean over the leading
+    client axis of every leaf."""
+    return jax.tree.map(lambda t: t.mean(axis=0), tree)
+
+
+_client_mean_jit = jax.jit(_client_mean)
 
 
 def make_controller(cfg: ArchConfig, n_labeled: int, n_total: int

@@ -1,7 +1,8 @@
 """The phase programs carry their names into the HLO module, and with it
 into the device trace: ``jit_supervised_phase``, ``jit_cross_entity_phase``
 and the LM path's ``jit_train_phase``, where a bare ``jit_phase`` named
-every scanned phase alike."""
+every scanned phase alike; the round's broadcast and FedAvg programs read
+``jit_broadcast`` and ``jit_fedavg`` on both executors."""
 import os
 from dataclasses import replace
 
@@ -33,8 +34,8 @@ def _cfg():
 
 @pytest.fixture(scope="module")
 def lowered_phases():
-    """Module names of the engine's phase programs, vmapped executor and
-    client-sharded executor on a one-device mesh."""
+    """Module names of the engine's phase, broadcast and FedAvg programs,
+    vmapped executor and client-sharded executor on a one-device mesh."""
     from repro.launch.mesh import make_host_mesh
     cfg = _cfg()
     n, b, side = 2, 8, cfg.image_size
@@ -56,6 +57,10 @@ def lowered_phases():
         semi = sys_.semi_phase_sharded if label == "sharded" \
             else sys_.semi_phase
         out[label, "cross_entity"] = _module_name(semi.lower(carry, xus))
+        out[label, "broadcast"] = _module_name(sys_._broadcast.lower(
+            state.params["bottom"], state.teacher["bottom"]))
+        out[label, "fedavg"] = _module_name(sys_._aggregate.lower(
+            bottoms, t_bottoms))
     return out
 
 
@@ -63,6 +68,13 @@ def lowered_phases():
 @pytest.mark.parametrize("phase", ["supervised", "cross_entity"])
 def test_engine_phase_programs_are_named(lowered_phases, executor, phase):
     assert lowered_phases[executor, phase] == f"jit_{phase}_phase"
+
+
+@pytest.mark.parametrize("executor", ["vmapped", "sharded"])
+@pytest.mark.parametrize("program", ["broadcast", "fedavg"])
+def test_broadcast_and_fedavg_programs_are_named(lowered_phases, executor,
+                                                 program):
+    assert lowered_phases[executor, program] == f"jit_{program}"
 
 
 def test_every_scan_builder_names_its_program():

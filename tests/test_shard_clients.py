@@ -121,7 +121,7 @@ SCRIPT = textwrap.dedent("""
         sys_ = SemiSFLSystem(cfg, n_clients_per_round=n_active,
                              mesh=make_host_mesh())
         state = sys_.init_state(0)
-        bottoms, t_bottoms = sys_._broadcast_sharded(
+        bottoms, t_bottoms = sys_._broadcast(
             state.params["bottom"], state.teacher["bottom"])
         carry = (bottoms, t_bottoms, state.params["top"],
                  state.params["proj"], state.teacher, state.queue,
