@@ -84,16 +84,15 @@ def make_rig(*, arch="paper-cnn", n_labeled=100, n_total=2400, n_test=300,
     return cfg, train, test, lab, cls
 
 
-def build_system(method: str, cfg, n_active: int, scan_rounds=None,
-                 mesh=None, prefetch=None, wire=None):
+def build_system(method: str, cfg, n_active: int, mesh=None,
+                 prefetch=None, wire=None):
     if method == "semisfl":
-        return SemiSFLSystem(cfg, n_clients_per_round=n_active,
-                             scan_rounds=scan_rounds, mesh=mesh,
+        return SemiSFLSystem(cfg, n_clients_per_round=n_active, mesh=mesh,
                              prefetch=prefetch, wire_format=wire)
     if method == "fedswitch-sl":
         return make_fedswitch_sl(cfg, n_clients_per_round=n_active,
-                                 scan_rounds=scan_rounds, mesh=mesh,
-                                 prefetch=prefetch, wire_format=wire)
+                                 mesh=mesh, prefetch=prefetch,
+                                 wire_format=wire)
     if wire is not None and not parse_wire_format(wire).identity:
         raise ValueError(f"wire format {wire!r} needs a split link; "
                          f"{method!r} exchanges full models")

@@ -138,10 +138,10 @@ def _smoke_lm_timings(log) -> dict:
 
 def run_smoke(out_dir: str) -> dict:
     """Tiny config end-to-end: exercises the data pipeline, the engine's
-    multi-client round (scanned, eager, client-sharded AND prefetched
+    multi-client round (vmapped, client-sharded AND prefetched
     executors), the dispatched clustering kernel, and the adaptation
     controller, in seconds.  Writes BENCH_smoke.json with
-    ``us_per_round_scanned`` / ``us_per_round_eager`` /
+    ``us_per_round_scanned`` /
     ``us_per_round_sharded`` / ``us_per_round_prefetch`` (+
     ``prefetch_overlap_frac``) so CI can gate executor regressions, the
     compressed-wire bytes (``bytes_per_round_{fp32,int8}`` +
@@ -159,27 +159,23 @@ def run_smoke(out_dir: str) -> dict:
     mesh = _smoke_mesh(n_active)
     log = lambda *a: print("#", *a)
     timings, res, pf_stats = {}, None, None
-    for mode, scan, m, pf in (("eager", False, None, None),
-                              ("scanned", True, None, None),
-                              ("sharded", True, mesh, None),
-                              ("prefetch", True, None, True)):
+    for mode, m, pf in (("scanned", None, None),
+                        ("sharded", mesh, None),
+                        ("prefetch", None, True)):
         rig = _smoke_rig()
-        sys_ = build_system("semisfl", rig[0], n_active, scan_rounds=scan,
-                            mesh=m, prefetch=pf)
+        sys_ = build_system("semisfl", rig[0], n_active, mesh=m,
+                            prefetch=pf)
         if m is not None:
             # a REPRO_* env override downgrading the executor would make
             # us record vmapped timings as "sharded" — refuse instead
             assert sys_._use_sharded, (
                 "sharded smoke entry fell back to the vmapped executor "
-                "(REPRO_SCAN_ROUNDS / REPRO_SHARD_CLIENTS override?)")
+                "(REPRO_SHARD_CLIENTS override?)")
         if pf:
             assert sys_.prefetch, (
                 "prefetch smoke entry fell back to the inline loaders")
         # warm-up rounds on the same system: jit tracing/compilation happens
         # here, so us_per_round below tracks engine time, not the compiler.
-        # 3 rounds: with the sharded executor the round-N inputs pass
-        # through up to three commitment states (host arrays -> mixed ->
-        # fully mesh-committed), each its own compile-cache entry
         run_method("semisfl", rounds=3, n_active=n_active, system=sys_,
                    rig=rig, log=log)
         t0 = time.time()
@@ -189,14 +185,13 @@ def run_smoke(out_dir: str) -> dict:
         if pf:
             pf_stats = sys_.prefetch_stats()
             sys_.close()
-    # wire-format entry: same rig, scanned executor, int8 split-link
+    # wire-format entry: same rig, vmapped executor, int8 split-link
     # payloads + top-k FedAvg deltas as real ops in the phase programs.
     # The bills then reflect actual on-wire dtypes/sparsity, so the smoke
     # record carries the compression ratio CI gates on.
     wire = "int8+topk0.05"
     rig = _smoke_rig()
-    sys_w = build_system("semisfl", rig[0], n_active, scan_rounds=True,
-                         wire=wire)
+    sys_w = build_system("semisfl", rig[0], n_active, wire=wire)
     run_method("semisfl", rounds=3, n_active=n_active, system=sys_w,
                rig=rig, log=log, wire=wire)
     t0 = time.time()
@@ -214,10 +209,8 @@ def run_smoke(out_dir: str) -> dict:
         # us_per_round keeps tracking the default executor (scanned)
         "us_per_round": round(timings["scanned"]),
         "us_per_round_scanned": round(timings["scanned"]),
-        "us_per_round_eager": round(timings["eager"]),
         "us_per_round_sharded": round(timings["sharded"]),
         "us_per_round_prefetch": round(timings["prefetch"]),
-        "scan_speedup": round(timings["eager"] / timings["scanned"], 2),
         # sharded-vs-vmapped on the scanned phase (>1: sharding pays off;
         # on a 1-device mesh this is the shard_map overhead ratio)
         "shard_speedup": round(timings["scanned"] / timings["sharded"], 2),
@@ -245,8 +238,8 @@ def run_smoke(out_dir: str) -> dict:
     os.makedirs(out_dir, exist_ok=True)
     with open(os.path.join(out_dir, "BENCH_smoke.json"), "w") as f:
         json.dump(rec, f, indent=2)
-    print(f"smoke,{rec['us_per_round']},final_acc={rec['final_acc']}"
-          f" scan_speedup={rec['scan_speedup']}x", flush=True)
+    print(f"smoke,{rec['us_per_round']},final_acc={rec['final_acc']}",
+          flush=True)
     return rec
 
 

@@ -18,16 +18,17 @@ One aggregation round h:
   (5) Bottom aggregation: FedAvg over client bottoms.
 
 Clients are simulated as a stacked leading axis on bottom parameters.
-Two executors drive the cross-entity phase:
+Each phase is one step function scanned into one program
+(``core/scan.py``), and two executors run the same cross-entity step:
 
-  * vmapped (default): vmap over clients inside one jitted step, scanned
-    per phase (``core/scan.py``);
-  * client-sharded (``mesh=`` + ``REPRO_SHARD_CLIENTS``): the same scan
+  * vmapped (default): vmap over clients inside the scanned step;
+  * client-sharded (``mesh=`` + ``REPRO_SHARD_CLIENTS``): the same step
     compiled under ``shard_map`` with the client axis sharded over the
-    mesh's data axes.  Per-client bottom updates run collective-free on
-    their shard; the top/proj gradient (Eq. (7)) is one psum; FedAvg is
-    one GSPMD all-reduce.  Both executors are numerically equivalent (see
-    tests/test_shard_clients.py).
+    mesh's data axes.  The step differs only in its collectives: per-client
+    bottom updates run collective-free on their shard; the loss
+    denominators and the top/proj gradient (Eq. (7)) are psums; the queue
+    write all-gathers; FedAvg is one GSPMD all-reduce.  Both executors are
+    numerically equivalent (see tests/test_shard_clients.py).
 
 Both run step (2), the broadcast, and step (5), FedAvg, as one compiled
 program each, ``jit_broadcast`` and ``jit_fedavg``; the sharded executor
@@ -56,7 +57,6 @@ from repro.core.wire import (WireFormatLike, fake_quantize, parse_wire_format,
                              quantize_grad, resolve_fmt, sparse_delta_mean)
 from repro.data.augment import strong_augment, weak_augment
 from repro.data.pipeline import (Loader, PodClients, select_pod_blocked,
-                                 stack_client_batches,
                                  stack_client_batches_many)
 from repro.data.prefetch import RoundPrefetcher, prefetch_default
 from repro.kernels import clustering_loss as fused_clustering_loss
@@ -65,11 +65,6 @@ from repro.obs import span, spanned
 from repro.optim import apply_updates, sgd
 
 Array = jax.Array
-
-
-def _scan_rounds_default() -> bool:
-    return os.environ.get("REPRO_SCAN_ROUNDS", "1").lower() not in (
-        "0", "false", "off")
 
 
 def _shard_clients_default() -> bool:
@@ -142,7 +137,6 @@ class SemiSFLSystem:
                  lr_schedule: Optional[Callable] = None,
                  use_clustering: bool = True,
                  use_supcon: bool = True,
-                 scan_rounds: Optional[bool] = None,
                  mesh=None,
                  shard_clients: Optional[bool] = None,
                  prefetch: Optional[bool] = None,
@@ -158,24 +152,17 @@ class SemiSFLSystem:
         self.lr_schedule = lr_schedule or (lambda step: jnp.float32(lr))
         self.use_clustering = use_clustering
         self.use_supcon = use_supcon
-        # scan-compiled round executor (default); the eager per-step path
-        # stays available for parity testing (REPRO_SCAN_ROUNDS=0 flips the
-        # default process-wide).
-        self.scan_rounds = (_scan_rounds_default() if scan_rounds is None
-                            else scan_rounds)
-        # client-sharded executor: active when a mesh is supplied, the scan
-        # executor is on, and REPRO_SHARD_CLIENTS (or the kwarg) says so.
+        # client-sharded executor: active when a mesh is supplied and
+        # REPRO_SHARD_CLIENTS (or the kwarg) says so.
         self.mesh = mesh
         self.shard_clients = (_shard_clients_default() if shard_clients
                               is None else shard_clients)
-        self._use_sharded = (mesh is not None and self.shard_clients
-                             and self.scan_rounds)
+        self._use_sharded = mesh is not None and self.shard_clients
         if mesh is not None and not self._use_sharded:
             import warnings
             warnings.warn(
                 "SemiSFLSystem got mesh= but the client-sharded executor "
-                "is OFF (scan_rounds and shard_clients must both be on — "
-                "check REPRO_SCAN_ROUNDS / REPRO_SHARD_CLIENTS); falling "
+                "is OFF (shard_clients / REPRO_SHARD_CLIENTS); falling "
                 "back to the vmapped executor", stacklevel=2)
         if self._use_sharded:
             from repro.launch.mesh import data_axes_size, mesh_axes
@@ -198,9 +185,8 @@ class SemiSFLSystem:
             if not self._use_sharded:
                 raise RuntimeError(
                     "multi-process execution requires the client-sharded "
-                    "scan executor: pass mesh=make_host_mesh(pods="
-                    "jax.process_count()) and leave REPRO_SCAN_ROUNDS / "
-                    "REPRO_SHARD_CLIENTS on")
+                    "executor: pass mesh=make_host_mesh(pods="
+                    "jax.process_count()) and leave REPRO_SHARD_CLIENTS on")
             from repro.launch.distributed import pod_index
             self._pod = pod_index(mesh)   # validates pod axis == processes
         # async double-buffered prefetch (data/prefetch.py): a worker
@@ -242,10 +228,11 @@ class SemiSFLSystem:
             round=jnp.zeros((), jnp.int32),
             step=jnp.zeros((), jnp.int32),
         )
-        if self._procs > 1:
+        if self._use_sharded:
             # every process built the same values from the same seed;
-            # commit them replicated over the global mesh so the phase
-            # programs see consistently-placed global inputs from round 0
+            # commit them replicated over the (global) mesh so the phase
+            # programs see consistently-placed inputs from round 0, and
+            # round 1 compiles no second broadcast for mesh-placed state
             from repro.launch.distributed import put_replicated
             state = put_replicated(state, self.mesh)
         return state
@@ -303,10 +290,8 @@ class SemiSFLSystem:
         has_dropout = cfg.arch_type == "cnn" and cfg.cnn_dropout > 0.0
 
         # ---------------- supervised step (PS, Alg.1 lines 4-5) ----------
-        # Carry-style ``(state, batch) -> (state, loss)``: the SAME function
-        # is jitted for the eager per-step path and scanned (core/scan.py)
-        # for the compiled phase, so the two paths are numerically identical
-        # by construction.
+        # Carry-style ``(state, batch) -> (state, loss)``, scanned
+        # (core/scan.py) into the compiled phase.
         def supervised_step(state: SemiSFLState, batch):
             x, y = batch
             if has_dropout:
@@ -348,11 +333,11 @@ class SemiSFLSystem:
             return new_state, loss
 
         supervised_step = self._at_config_precision(supervised_step)
-        self.supervised_step = jax.jit(supervised_step)
         self.supervised_phase = scan_phase(supervised_step,
                                            name="supervised_phase")
         # raw (unjitted) step, for building phase variants with explicit
         # scan policies (benchmarks/roofline.py scan-unroll micro-bench)
+        # and for the tests' per-step reference loop
         self._supervised_step_fn = supervised_step
 
         # --------------- cross-entity semi-supervised step ----------------
@@ -415,27 +400,72 @@ class SemiSFLSystem:
                         "dropout_keys": dropout_keys}, mode="train")
             return out, feats_flat
 
+        # The executor's client axes and the three trace-time helpers that
+        # are all the two executors differ by.  Vmapped (``axes is None``),
+        # each is the identity and the step sees all N clients.  Under
+        # shard_map the step sees its local client block: it takes its
+        # rows of the global key schedule, psums the loss denominators, the
+        # Eq. (7) gradient and the reported loss, and all-gathers the
+        # memory-queue write so the replicated queue stays bit-identical to
+        # the vmapped executor's.  Sum-form losses over psum'd global
+        # denominators make per-shard gradients EXACT pieces of the
+        # global-mean gradient, so Eq. (8) needs no collective at all.
+        axes = self._data_axes if self._use_sharded else None
+        n_shards = self._n_shards if self._use_sharded else 1
+
+        def local(keys, rows):
+            """This shard's ``rows`` rows of a global key array."""
+            if axes is None:
+                return keys
+            return jax.lax.dynamic_slice_in_dim(
+                keys, compat.axis_index(axes) * rows, rows)
+
+        def psum(x):
+            return x if axes is None else jax.lax.psum(x, axes)
+
+        def gather(x):
+            if axes is None:
+                return x
+            return jax.lax.all_gather(x, axes, axis=0, tiled=True)
+
         def semi_step(carry, xu):
-            """xu: (N, B, H, W, C) unlabeled client batches."""
+            """xu: (n_local, B, H, W, C) unlabeled batches of this
+            executor's client block (all N clients when vmapped)."""
             (client_bottoms, client_teacher_bottoms, params_top, params_proj,
              teacher, queue, rng, step) = carry
-            n, b = xu.shape[0], xu.shape[1]
+            n_local, b = xu.shape[0], xu.shape[1]
+            n = n_local * n_shards                  # global client count
             if has_dropout:
                 rng, kw, ks_, kd = jax.random.split(rng, 4)
-                kds = jax.random.split(kd, n * b)   # per-sample dropout keys
+                # per-sample dropout keys
+                kds = local(jax.random.split(kd, n * b), n_local * b)
             else:
                 rng, kw, ks_ = jax.random.split(rng, 3)
                 kds = None
-            xw = jax.vmap(weak_augment)(jax.random.split(kw, n), xu)
-            xs = jax.vmap(strong_augment)(jax.random.split(ks_, n), xu)
+            xw = jax.vmap(weak_augment)(
+                local(jax.random.split(kw, n), n_local), xu)
+            xs = jax.vmap(strong_augment)(
+                local(jax.random.split(ks_, n), n_local), xu)
             lr = self.lr_schedule(step)
 
             pseudo, conf_ok, tz = teacher_targets(
                 teacher, client_teacher_bottoms, xw)
 
+            # global loss denominators: with axes, the only pre-gradient
+            # collectives, two scalars
+            m_cnt = psum(conf_ok.astype(jnp.float32).sum())
+            m_norm = jnp.maximum(m_cnt, 1.0)
+            if self.use_clustering and axes is not None:
+                cl_cnt = losses.clustering_anchor_count(
+                    pseudo, conf_ok, queue.label, queue.conf,
+                    queue.valid).astype(jnp.float32)
+                cl_norm = jnp.maximum(psum(cl_cnt), 1.0)
+
             def loss_fn(bottoms, top, proj):
                 out, feats_flat = student_forward(bottoms, top, xs, kds)
-                h = losses.cross_entropy(out["logits"], pseudo, mask=conf_ok)
+                h_sum, _ = losses.cross_entropy_sum(out["logits"], pseudo,
+                                                    mask=conf_ok)
+                h = h_sum / m_norm
                 c = 0.0
                 if self.use_clustering:
                     z = apply_projection_head(proj, cfg,
@@ -447,15 +477,20 @@ class SemiSFLSystem:
                     c = fused_clustering_loss(
                         z, pseudo, conf_ok, queue.z,
                         queue.label, queue.conf, queue.valid, s.temperature)
+                    if axes is not None:
+                        # this shard's piece of the global anchor mean
+                        c = c * jnp.maximum(cl_cnt, 1.0) / cl_norm
                 return h + c, (h, c)
 
-            (loss, (h, c)), grads = jax.value_and_grad(
+            (loss, (h, _)), grads = jax.value_and_grad(
                 loss_fn, argnums=(0, 1, 2), has_aux=True)(
                 client_bottoms, params_top, params_proj)
             g_bottoms, g_top, g_proj = grads
-            # Eq.(7): server-side mean over clients (global mean == /1, the
-            # loss already averages over all N*B samples); Eq.(8): each
-            # client applies its own gradient — undo the 1/N factor.
+            # Eq. (7): the top/proj gradient already carries the global
+            # 1/M normalization, so the client-mean gradient is its psum;
+            # Eq. (8): each client applies its own gradient — undo the
+            # global mean's 1/N factor.
+            g_top, g_proj = psum((g_top, g_proj))
             g_bottoms = jax.tree.map(lambda g: g * n, g_bottoms)
             new_bottoms = jax.tree.map(lambda p, g: p - lr * g,
                                        client_bottoms, g_bottoms)
@@ -464,121 +499,17 @@ class SemiSFLSystem:
                                     g_proj)
             new_teacher_bottoms = ema_update(client_teacher_bottoms,
                                              new_bottoms, s.ema_decay)
-            queue = enqueue(queue, tz, pseudo, conf_ok)
-            mask_rate = 1.0 - conf_ok.astype(jnp.float32).mean()
-            new_carry = (new_bottoms, new_teacher_bottoms, new_top, new_proj,
-                         teacher, queue, rng, step + 1)
-            return new_carry, (loss, h, mask_rate)
-
-        semi_step = self._at_config_precision(semi_step)
-        self.semi_step = jax.jit(semi_step)
-        self.semi_phase = scan_phase(semi_step, name="cross_entity_phase")
-
-        # ------- step (5) with top-k sparsified bottom deltas --------------
-        # Each client uploads the top-frac entries of its delta against the
-        # broadcast reference; FedAvg reconstructs reference + mean(deltas).
-        # Only built when the wire asks for it — the identity wire keeps
-        # the exact historical aggregate programs.
-        topk_frac = self.wire.topk_frac
-        if topk_frac < 1.0:
-            def aggregate_topk(bottoms, t_bottoms, ref_b, ref_t):
-                return (sparse_delta_mean(bottoms, ref_b, topk_frac),
-                        sparse_delta_mean(t_bottoms, ref_t, topk_frac))
-            self._aggregate_topk = jax.jit(aggregate_topk)
-
-        # ------------- client-sharded cross-entity step --------------------
-        # Same mathematics as semi_step, reorganized for shard_map: the
-        # shard sees its local client block; sum-form losses + psum'd
-        # global denominators make per-shard gradients EXACT pieces of the
-        # global-mean gradient, so Eq. (7) is one psum and Eq. (8) needs no
-        # collective at all.  The memory-queue write all-gathers the (tiny)
-        # projected features so the replicated queue stays bit-identical to
-        # the vmapped executor's.
-        def semi_step_sharded(carry, xu):
-            """xu: (n_local, B, H, W, C) — this shard's client block."""
-            (client_bottoms, client_teacher_bottoms, params_top, params_proj,
-             teacher, queue, rng, step) = carry
-            axes = self._data_axes
-            n_local, b = xu.shape[0], xu.shape[1]
-            n = n_local * self._n_shards            # global client count
-            off = compat.axis_index(axes) * n_local
-            # identical global key schedule as the vmapped step — slice
-            # this shard's client block so augmentation + dropout masks
-            # match the vmapped executor exactly
-            slice_ = jax.lax.dynamic_slice_in_dim
-            if has_dropout:
-                rng, kw, ks_, kd = jax.random.split(rng, 4)
-                kds = slice_(jax.random.split(kd, n * b), off * b,
-                             n_local * b)
-            else:
-                rng, kw, ks_ = jax.random.split(rng, 3)
-                kds = None
-            kws = slice_(jax.random.split(kw, n), off, n_local)
-            kss = slice_(jax.random.split(ks_, n), off, n_local)
-            xw = jax.vmap(weak_augment)(kws, xu)
-            xs = jax.vmap(strong_augment)(kss, xu)
-            lr = self.lr_schedule(step)
-
-            pseudo, conf_ok, tz = teacher_targets(
-                teacher, client_teacher_bottoms, xw)
-
-            # global loss denominators: the only pre-gradient collectives,
-            # two scalars
-            m_cnt = jax.lax.psum(conf_ok.astype(jnp.float32).sum(), axes)
-            m_norm = jnp.maximum(m_cnt, 1.0)
-            cl_cnt = cl_norm = jnp.float32(1.0)
-            if self.use_clustering:
-                cl_cnt = losses.clustering_anchor_count(
-                    pseudo, conf_ok, queue.label, queue.conf,
-                    queue.valid).astype(jnp.float32)
-                cl_norm = jnp.maximum(jax.lax.psum(cl_cnt, axes), 1.0)
-
-            def loss_fn(bottoms, top, proj):
-                out, feats_flat = student_forward(bottoms, top, xs, kds)
-                h_sum, _ = losses.cross_entropy_sum(out["logits"], pseudo,
-                                                    mask=conf_ok)
-                loss = h_sum / m_norm
-                c_sum = jnp.float32(0.0)
-                if self.use_clustering:
-                    z = apply_projection_head(proj, cfg,
-                                              pool_features(cfg, feats_flat))
-                    c_local = fused_clustering_loss(
-                        z, pseudo, conf_ok, queue.z,
-                        queue.label, queue.conf, queue.valid, s.temperature)
-                    c_sum = c_local * jnp.maximum(cl_cnt, 1.0)
-                    loss = loss + c_sum / cl_norm
-                return loss, (h_sum, c_sum)
-
-            (_, (h_sum, c_sum)), grads = jax.value_and_grad(
-                loss_fn, argnums=(0, 1, 2), has_aux=True)(
-                client_bottoms, params_top, params_proj)
-            g_bottoms, g_top, g_proj = grads
-            # Eq. (7): every shard's top/proj grad already carries the
-            # global 1/M normalization, so the client-mean gradient is ONE
-            # psum; Eq. (8): own gradient, collective-free — undo the
-            # global mean's 1/N factor
-            g_top = jax.lax.psum(g_top, axes)
-            g_proj = jax.lax.psum(g_proj, axes)
-            g_bottoms = jax.tree.map(lambda g: g * n, g_bottoms)
-            new_bottoms = jax.tree.map(lambda p, g: p - lr * g,
-                                       client_bottoms, g_bottoms)
-            new_top = jax.tree.map(lambda p, g: p - lr * g, params_top, g_top)
-            new_proj = jax.tree.map(lambda p, g: p - lr * g, params_proj,
-                                    g_proj)
-            new_teacher_bottoms = ema_update(client_teacher_bottoms,
-                                             new_bottoms, s.ema_decay)
-            gather = lambda v: jax.lax.all_gather(v, axes, axis=0, tiled=True)
             queue = enqueue(queue, gather(tz), gather(pseudo),
                             gather(conf_ok))
-            h = jax.lax.psum(h_sum, axes) / m_norm
-            loss = h + (jax.lax.psum(c_sum, axes) / cl_norm
-                        if self.use_clustering else 0.0)
+            loss, h = psum((loss, h))
             mask_rate = 1.0 - m_cnt / (n * b)
             new_carry = (new_bottoms, new_teacher_bottoms, new_top, new_proj,
                          teacher, queue, rng, step + 1)
             return new_carry, (loss, h, mask_rate)
 
-        self.semi_step_sharded = self._at_config_precision(semi_step_sharded)
+        semi_step = self._at_config_precision(semi_step)
+        # raw (unjitted) step, for the tests' per-step reference loop
+        self._semi_step_fn = semi_step
 
         # ---------------- steps (2) and (5): broadcast and FedAvg ---------
         # One compiled program each, for both executors; their HLO modules
@@ -597,13 +528,32 @@ class SemiSFLSystem:
         def fedavg(bottoms, t_bottoms):
             return _client_mean(bottoms), _client_mean(t_bottoms)
 
+        # Step (5) with top-k sparsified bottom deltas: each client uploads
+        # the top-frac entries of its delta against the broadcast
+        # reference; FedAvg reconstructs reference + mean(deltas).  Per
+        # client, top-k is collective-free on the client-sharded stack; the
+        # delta mean is the same one all-reduce FedAvg compiles to.
+        topk_frac = self.wire.topk_frac
+
+        def aggregate_topk(bottoms, t_bottoms, ref_b, ref_t):
+            return (sparse_delta_mean(bottoms, ref_b, topk_frac),
+                    sparse_delta_mean(t_bottoms, ref_t, topk_frac))
+
         pin_stacked, pin_replicated = {}, {}
         if self._use_sharded:
-            stacked_sh, rep_sh = self._build_sharded_exec()
+            self.semi_phase, stacked_sh, rep_sh = self._build_sharded_exec(
+                semi_step)
             pin_stacked = {"out_shardings": (stacked_sh, stacked_sh)}
             pin_replicated = {"out_shardings": (rep_sh, rep_sh)}
+        else:
+            self.semi_phase = scan_phase(semi_step,
+                                         name="cross_entity_phase")
         self._broadcast = jax.jit(broadcast, **pin_stacked)
         self._aggregate = jax.jit(fedavg, **pin_replicated)
+        # only built when the wire asks for it: the identity wire keeps the
+        # plain FedAvg program
+        if topk_frac < 1.0:
+            self._aggregate_topk = jax.jit(aggregate_topk, **pin_replicated)
 
         # ---------------- evaluation (teacher model, Section V-B) ---------
         def eval_batch(params, x, y):
@@ -612,11 +562,11 @@ class SemiSFLSystem:
 
         self.eval_batch = jax.jit(self._at_config_precision(eval_batch))
 
-    def _build_sharded_exec(self):
-        """Compile the client-sharded executor's shard_map'd scan phase;
-        returns the shardings of a client stack and of a replicated
-        bottom, which pin the broadcast (step (2)) and FedAvg (step (5))
-        programs to the mesh."""
+    def _build_sharded_exec(self, semi_step):
+        """The client-sharded executor's shard_map'd scan of ``semi_step``;
+        returns it with the shardings of a client stack and of a
+        replicated bottom, which pin the broadcast (step (2)) and FedAvg
+        (step (5)) programs to the mesh."""
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from repro.sharding.specs import (client_batch_pspec,
@@ -645,8 +595,8 @@ class SemiSFLSystem:
         carry_specs = semi_carry_pspecs(carry_abs, axes)
         batch_specs = client_batch_pspec(6, axes, client_dim=1)  # (K,N,B,...)
         out_specs = (P(None), P(None), P(None))     # stacked loss/h/mask
-        self.semi_phase_sharded = sharded_scan_phase(
-            self.semi_step_sharded, mesh=mesh, carry_specs=carry_specs,
+        phase = sharded_scan_phase(
+            semi_step, mesh=mesh, carry_specs=carry_specs,
             batch_specs=batch_specs, out_specs=out_specs,
             name="cross_entity_phase")
 
@@ -682,19 +632,7 @@ class SemiSFLSystem:
         stacked_sh = tree_shardings(mesh, leading_axis_pspecs(abs_stack,
                                                               axes))
         rep_sh = tree_shardings(mesh, replicated_pspecs(abs_bottom))
-        if self.wire.topk_frac < 1.0:
-            frac = self.wire.topk_frac
-
-            def _aggregate_topk(bottoms, t_bottoms, ref_b, ref_t):
-                # per-client top-k is collective-free on the client-sharded
-                # stack; the delta mean is the same one all-reduce FedAvg
-                # compiles to
-                return (sparse_delta_mean(bottoms, ref_b, frac),
-                        sparse_delta_mean(t_bottoms, ref_t, frac))
-
-            self._aggregate_sharded_topk = jax.jit(
-                _aggregate_topk, out_shardings=(rep_sh, rep_sh))
-        return stacked_sh, rep_sh
+        return phase, stacked_sh, rep_sh
 
     # ------------------------------------------------------------------
     # round driver
@@ -764,11 +702,6 @@ class SemiSFLSystem:
         return self._broadcast(state.params["bottom"],
                                state.teacher["bottom"])
 
-    @staticmethod
-    def aggregate(client_bottoms):
-        """Step (5): FedAvg over the client axis of one stacked tree."""
-        return _client_mean_jit(client_bottoms)
-
     @spanned("round")
     def run_round(self, state: SemiSFLState, labeled: Loader,
                   client_loaders_: list[Loader], controller: FreqController,
@@ -777,19 +710,16 @@ class SemiSFLSystem:
                   ) -> tuple[SemiSFLState, RoundMetrics]:
         """Drive one aggregation round; returns the NEW state + metrics.
 
-        With the scanned executor (default) the incoming ``state``'s
-        buffers are DONATED to the phase programs: on accelerator
-        backends do not reuse ``state`` after this call (keep
-        ``jax.tree.map(jnp.copy, state)`` for rollback/best-checkpoint
-        logic, or run with ``scan_rounds=False``).  CPU ignores
-        donation.
+        The incoming ``state``'s buffers are DONATED to the phase
+        programs: on accelerator backends do not reuse ``state`` after
+        this call (keep ``jax.tree.map(jnp.copy, state)`` for
+        rollback/best-checkpoint logic).  CPU ignores donation.
 
-        A scanned round reads the chip once, at its end: everything of
-        the round is dispatched first (supervised phase, selection,
-        broadcast, client stack, cross-entity phase, FedAvg), then both
-        phases' losses and mask rates come back in one transfer
-        (``_host_all``), then the controller updates.  The eager path
-        reads each step's outputs as it goes.
+        A round reads the chip once, at its end: everything of the round
+        is dispatched first (supervised phase, selection, broadcast,
+        client stack, cross-entity phase, FedAvg), then both phases'
+        losses and mask rates come back in one transfer (``_host_all``),
+        then the controller updates.
 
         Client selection draws from a host-side RandomState created once
         per run (``init_state`` seeds it; ``rng_np`` overrides it) — never
@@ -806,7 +736,7 @@ class SemiSFLSystem:
         process owns); single-process runs may use it to reproduce the
         multi-process sample streams exactly.
 
-        With ``prefetch=`` / ``REPRO_PREFETCH`` on, the phase drivers
+        With ``prefetch=`` / ``REPRO_PREFETCH`` on, the phase programs
         consume ready device buffers from a background worker
         (``data/prefetch.py``) instead of calling the loaders inline, and
         the worker starts assembling the NEXT round's stacks before this
@@ -852,33 +782,16 @@ class SemiSFLSystem:
 
         # (1) supervised phase.  The LR schedule runs off the cumulative
         # step counter carried in the state — NOT round * (k_s_init + k_u),
-        # which skips steps once Eq. (10) shrinks K_s.  A scanned phase's
-        # losses stay on the device until the round's one read at its end.
+        # which skips steps once Eq. (10) shrinks K_s.  The phase's losses
+        # stay on the device until the round's one read at its end.
         if pf is not None:
             xs_d, ys_d = pf.get_supervised(k_s)   # already on device
-        if self.scan_rounds:
-            if pf is None:
-                with span("batch.labeled"):
-                    xs_d, ys_d = self._sup_put(*labeled.next_many(k_s))
-            with span("phase.supervised"):
-                state, f_s_acc = self.supervised_phase(state, (xs_d, ys_d))
-            del xs_d, ys_d        # its device buffers free once consumed
         else:
-            f_s_acc = []
-            for i in range(k_s):
-                if pf is not None:
-                    # static slice, not `xs_d[i]`: integer indexing
-                    # commits the index constant (an implicit transfer
-                    # the parity tests' guard rejects)
-                    batch = (jax.lax.index_in_dim(xs_d, i, keepdims=False),
-                             jax.lax.index_in_dim(ys_d, i, keepdims=False))
-                else:
-                    with span("batch.labeled"):
-                        x, y = labeled.next()
-                        batch = (jnp.asarray(x), jnp.asarray(y))
-                with span("phase.supervised"):
-                    state, loss = self.supervised_step(state, batch)
-                f_s_acc.append(float(_host(loss)))
+            with span("batch.labeled"):
+                xs_d, ys_d = self._sup_put(*labeled.next_many(k_s))
+        with span("phase.supervised"):
+            state, f_s_acc = self.supervised_phase(state, (xs_d, ys_d))
+        del xs_d, ys_d            # its device buffers free once consumed
 
         # (2) broadcast
         if active is None:
@@ -919,7 +832,7 @@ class SemiSFLSystem:
                  state.step)
         if k_u == 0:
             f_u_acc, mask_acc = np.zeros((0,)), np.zeros((0,))
-        elif self.scan_rounds:        # the client-sharded executor included
+        else:
             if pf is not None:
                 xus = pf.get_clients(stack_active, k_u)  # on device/shards
             else:
@@ -929,29 +842,11 @@ class SemiSFLSystem:
                         client_loaders_, stack_active, k_u, shardings=sh)
                     if sh is None:
                         xus = jnp.asarray(xus)
-            phase = (self.semi_phase_sharded if self._use_sharded
-                     else self.semi_phase)
             with span("phase.cross_entity"):
-                carry, (f_u_acc, _h, mask_acc) = phase(carry, xus)
+                carry, (f_u_acc, _h, mask_acc) = self.semi_phase(carry, xus)
             del xus
-        else:
-            if pf is not None:
-                xus = pf.get_clients(stack_active, k_u)  # on device
-            f_u_acc, mask_acc = [], []
-            for i in range(k_u):
-                if pf is not None:
-                    xu = jax.lax.index_in_dim(xus, i, keepdims=False)
-                else:
-                    with span("batch.clients"):
-                        xu, _ = stack_client_batches(client_loaders_,
-                                                     stack_active)
-                        xu = jnp.asarray(xu)
-                with span("phase.cross_entity"):
-                    carry, (loss, _h, mask_rate) = self.semi_step(carry, xu)
-                f_u_acc.append(float(_host(loss)))
-                mask_acc.append(float(_host(mask_rate)))
         if pf is not None:
-            # both phases are dispatched (scanned modes: not yet synced):
+            # both phases are dispatched, not yet synced:
             # start assembling the NEXT round's stacks now, so the worker
             # runs while this round executes and while metrics sync below.
             pf.speculate(k_s, selection_rng(self, rng_np))
@@ -965,13 +860,8 @@ class SemiSFLSystem:
         # carry (so it survives donation), and the carry-returned teacher's
         # bottom is threaded through the phase unchanged — both ARE the
         # broadcast-time values.
-        sparse = self.wire.topk_frac < 1.0
         with span("fedavg"):
-            if self._use_sharded and sparse:
-                agg_bottom, agg_t_bottom = self._aggregate_sharded_topk(
-                    bottoms, t_bottoms, state.params["bottom"],
-                    teacher["bottom"])
-            elif sparse:
+            if self.wire.topk_frac < 1.0:
                 agg_bottom, agg_t_bottom = self._aggregate_topk(
                     bottoms, t_bottoms, state.params["bottom"],
                     teacher["bottom"])
@@ -986,10 +876,9 @@ class SemiSFLSystem:
                              step=step)
 
         # metric sync point, after everything of the round is dispatched:
-        # the round's one device-to-host read (the eager path's values are
-        # on the host already).  Read first, then reduce with numpy, so the
-        # scanned executors' device arrays reduce in numpy's host order,
-        # not with jnp's on-device .mean().  Every process reads the same
+        # the round's one device-to-host read.  Read first, then reduce
+        # with numpy, so the phases' device arrays reduce in numpy's host
+        # order, not with jnp's on-device .mean().  Every process reads the same
         # replicated values, so the controller — and with it the next
         # round's K_s — stays in lockstep fleet-wide.
         f_s_acc, f_u_acc, mask_acc = _host_all(f_s_acc, f_u_acc, mask_acc)
@@ -1024,9 +913,6 @@ def _client_mean(tree):
     """FedAvg of one client-stacked tree: the mean over the leading
     client axis of every leaf."""
     return jax.tree.map(lambda t: t.mean(axis=0), tree)
-
-
-_client_mean_jit = jax.jit(_client_mean)
 
 
 def make_controller(cfg: ArchConfig, n_labeled: int, n_total: int

@@ -34,7 +34,7 @@ def default_unroll() -> Union[int, bool]:
     Default is the rolled loop (unroll=1): compile time stays flat in
     ``K`` and the loop construct is what the client-axis sharding PR will
     scan over.  Measured on the 2-core CI-class CPU: rolled is ~3x faster
-    than the eager per-step path on the dispatch-bound smoke config, but
+    than a per-step jitted loop on the dispatch-bound smoke config, but
     XLA:CPU compiles the *larger* smoke CNN's conv fwd/bwd ~2x slower
     inside a ``while`` loop — set ``REPRO_SCAN_UNROLL=full`` (or an
     integer factor) to trade compile time for that back.
@@ -78,7 +78,7 @@ def scan_phase(step_fn: Callable[[Carry, Batch], Tuple[Carry, Any]], *,
     """Build a compiled K-iteration phase from a single-iteration step.
 
     ``step_fn`` must be a pure ``(carry, batch) -> (carry, out)``
-    function (the same one the eager per-step path jits), ``batches`` a
+    function (the same one a per-step reference loop jits), ``batches`` a
     pytree whose leaves all share a leading ``K`` axis.  Retraces happen
     only when ``K`` or the batch shapes change (e.g. when the Eq. (10)
     controller shrinks ``K_s``) — a handful of compilations per run.

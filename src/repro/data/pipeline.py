@@ -93,7 +93,7 @@ class Loader:
         """Prefetch ``k`` batches -> ``(K, B, ...)`` stacks for the
         scan-compiled phase executor.  Draws exactly the same sample
         sequence as ``k`` successive :meth:`next` calls, so the scanned
-        and eager round paths see identical data."""
+        phase and a per-step loop see identical data."""
         xs, ys = zip(*(self.next() for _ in range(k)))
         return np.stack(xs), np.stack(ys)
 
@@ -124,7 +124,7 @@ def stack_client_batches_many(loaders: list[Loader], active: list[int],
     client's ``(K, B, ...)`` slab wraps its partition at the loader's own
     deterministic epoch boundary (see :class:`Loader`) — a client whose
     partition is smaller than ``k * batch`` recycles samples at exactly
-    ``len(loader)`` draws, in phase with the eager path.
+    ``len(loader)`` draws, in phase with successive single draws.
 
     With ``shardings=(x_sharding, y_sharding)`` (NamedShardings whose spec
     puts the client axis on the mesh's data axes) the stacks are

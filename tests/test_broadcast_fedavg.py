@@ -1,8 +1,9 @@
 """Broadcast (step (2)) and FedAvg (step (5)) run as one compiled program
-each on the vmapped executor too: every call gives what the per-leaf
-formulas ``broadcast_to(...).copy()`` and ``mean(axis=0)`` give, bitwise
-for the broadcast and to 1e-7 relative for the mean, over two rounds of
-the scanned and of the eager round executor; whole rounds end in the
+each: every call gives what the per-leaf formulas
+``broadcast_to(...).copy()`` and ``mean(axis=0)`` give, bitwise for the
+broadcast and to 1e-7 relative for the mean, over two rounds of the
+vmapped executor and of the client-sharded executor on a one-device
+mesh, whose programs pin their outputs to it; whole rounds end in the
 same state; and a run at a fixed K_s compiles each program once."""
 import os
 
@@ -13,13 +14,12 @@ import pytest
 
 from repro.core.engine import SemiSFLSystem, make_controller
 
-from test_scan_parity import _get, _rig, _tiny_cfg
+from test_scan_parity import _executor, _get, _rig, _tiny_cfg
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 N_ACTIVE = 3
-EXECUTORS = pytest.mark.parametrize("scan_rounds", [True, False],
-                                    ids=["scanned", "eager"])
+EXECUTORS = pytest.mark.parametrize("executor", ["scanned", "sharded"])
 
 
 def eager_broadcast(global_bottom, teacher_bottom):
@@ -35,12 +35,12 @@ def eager_fedavg(bottoms, t_bottoms):
     return jax.tree.map(mean, bottoms), jax.tree.map(mean, t_bottoms)
 
 
-def _system(scan_rounds):
+def _system(executor):
     cfg = _tiny_cfg()
     with jax.transfer_guard("allow"):      # setup constants
         train, _, lab, cls = _rig(cfg)
         sys_ = SemiSFLSystem(cfg, n_clients_per_round=N_ACTIVE,
-                             scan_rounds=scan_rounds)
+                             **_executor(executor))
         state = sys_.init_state(0)
         ctrl = make_controller(cfg, 40, len(train.y))
     return sys_, state, lab, cls, ctrl
@@ -69,9 +69,9 @@ def _put(tree):
 
 
 @EXECUTORS
-def test_each_call_matches_the_per_leaf_formulas(scan_rounds,
+def test_each_call_matches_the_per_leaf_formulas(executor,
                                                  no_implicit_transfers):
-    sys_, state, lab, cls, ctrl = _system(scan_rounds)
+    sys_, state, lab, cls, ctrl = _system(executor)
     b_calls, f_calls = [], []
     sys_._broadcast = _recorded(sys_._broadcast, b_calls)
     sys_._aggregate = _recorded(sys_._aggregate, f_calls)
@@ -91,10 +91,10 @@ def test_each_call_matches_the_per_leaf_formulas(scan_rounds,
 
 @EXECUTORS
 def test_rounds_end_in_the_state_of_the_per_leaf_formulas(
-        scan_rounds, no_implicit_transfers):
-    sys_, state, lab, cls, ctrl = _system(scan_rounds)
+        executor, no_implicit_transfers):
+    sys_, state, lab, cls, ctrl = _system(executor)
     compiled = _get(_rounds(sys_, state, lab, cls, ctrl, 2))
-    sys_, state, lab, cls, ctrl = _system(scan_rounds)
+    sys_, state, lab, cls, ctrl = _system(executor)
     sys_._broadcast, sys_._aggregate = eager_broadcast, eager_fedavg
     per_leaf = _get(_rounds(sys_, state, lab, cls, ctrl, 2))
     for got, ref in zip(jax.tree.leaves(compiled),
@@ -105,8 +105,8 @@ def test_rounds_end_in_the_state_of_the_per_leaf_formulas(
 
 @EXECUTORS
 def test_a_run_at_fixed_k_s_compiles_each_program_once(
-        scan_rounds, no_implicit_transfers):
-    sys_, state, lab, cls, ctrl = _system(scan_rounds)
+        executor, no_implicit_transfers):
+    sys_, state, lab, cls, ctrl = _system(executor)
     _rounds(sys_, state, lab, cls, ctrl, 3, k_s=2)
     assert sys_._broadcast._cache_size() == 1
     assert sys_._aggregate._cache_size() == 1

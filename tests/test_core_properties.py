@@ -37,10 +37,10 @@ def test_ema_fixed_point(gamma):
 
 @given(st.integers(1, 6), st.integers(1, 8))
 def test_fedavg_identical_clients_is_identity(n_clients, dim):
-    from repro.core.engine import SemiSFLSystem
+    from repro.core.engine import _client_mean
     w = jnp.arange(float(dim))
     stacked = {"w": jnp.broadcast_to(w, (n_clients, dim))}
-    agg = SemiSFLSystem.aggregate(stacked)
+    agg = _client_mean(stacked)
     assert np.allclose(agg["w"], w)
 
 
@@ -48,8 +48,8 @@ def test_fedavg_identical_clients_is_identity(n_clients, dim):
 def test_fedavg_linearity(n):
     rngs = np.random.RandomState(0)
     ws = rngs.randn(n, 5).astype(np.float32)
-    from repro.core.engine import SemiSFLSystem
-    agg = SemiSFLSystem.aggregate({"w": jnp.asarray(ws)})
+    from repro.core.engine import _client_mean
+    agg = _client_mean({"w": jnp.asarray(ws)})
     assert np.allclose(agg["w"], ws.mean(0), atol=1e-6)
 
 

@@ -644,8 +644,7 @@ def test_prefetcher_rebinds_on_selection_policy_change():
                                  uniform_partition(0, len(un), 4)], 8, 1)
     pc = PodClients(cls, 4, 2, pod=None)
 
-    sys_ = SemiSFLSystem(cfg, n_clients_per_round=2, scan_rounds=True,
-                         prefetch=True)
+    sys_ = SemiSFLSystem(cfg, n_clients_per_round=2, prefetch=True)
     state = sys_.init_state(0)
     ctrl = make_controller(cfg, 32, len(train.y))
     state, _ = sys_.run_round(state, lab, pc, ctrl)

@@ -54,9 +54,8 @@ def lowered_phases():
                  state.params["proj"], state.teacher, state.queue,
                  state.rng, state.step)
         xus = jnp.zeros((2, n, b, side, side, 3), jnp.float32)
-        semi = sys_.semi_phase_sharded if label == "sharded" \
-            else sys_.semi_phase
-        out[label, "cross_entity"] = _module_name(semi.lower(carry, xus))
+        out[label, "cross_entity"] = _module_name(
+            sys_.semi_phase.lower(carry, xus))
         out[label, "broadcast"] = _module_name(sys_._broadcast.lower(
             state.params["bottom"], state.teacher["bottom"]))
         out[label, "fedavg"] = _module_name(sys_._aggregate.lower(

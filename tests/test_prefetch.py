@@ -5,8 +5,8 @@ Correctness under concurrency is PROVED here, not assumed:
   * the prefetched executor is bit-for-bit identical to the synchronous
     one over multiple rounds INCLUDING a K_s adaptation round (which
     forces the cancel/reshape path: the worker speculated with the old
-    phase length and must roll the labeled stream back), for the eager,
-    scanned, and 8-device client-sharded executors;
+    phase length and must roll the labeled stream back), for the vmapped
+    executor and the client-sharded one on one and on 8 devices;
   * a worker exception propagates to the caller (chained) and leaves no
     live prefetch threads (asserted via ``threading.enumerate()``);
   * shutting down mid-speculation rolls the loaders back to exactly the
@@ -29,6 +29,7 @@ from repro.data import (Loader, client_loaders, make_image_dataset,
                         train_test_split, uniform_partition)
 from repro.data.prefetch import (THREAD_NAME, Prefetcher, PrefetchError,
                                  RoundPrefetcher)
+from repro.launch.mesh import make_client_mesh
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
@@ -68,7 +69,7 @@ def _same_pos(a, b):
             and np.array_equal(a[2][1], b[2][1]) and a[2][2] == b[2][2])
 
 
-def _run(cfg, *, prefetch, scan_rounds, rounds=3):
+def _run(cfg, *, prefetch, mesh=None, rounds=3):
     """3 rounds with a FORCED Eq. (10) shrink on the last one — with
     prefetch on, the worker has already speculated the old K_s by then,
     so the cancel/reshape path is exercised every run."""
@@ -76,8 +77,8 @@ def _run(cfg, *, prefetch, scan_rounds, rounds=3):
     # so the round loop runs under the fixture's transfer-guard net
     with jax.transfer_guard("allow"):
         train, lab, cls = _rig(cfg)
-        sys_ = SemiSFLSystem(cfg, n_clients_per_round=3,
-                             scan_rounds=scan_rounds, prefetch=prefetch)
+        sys_ = SemiSFLSystem(cfg, n_clients_per_round=3, mesh=mesh,
+                             prefetch=prefetch)
         state = sys_.init_state(0)
         ctrl = make_controller(cfg, 40, len(train.y))
     metrics = []
@@ -99,15 +100,14 @@ def _assert_states_bitwise_equal(a, b):
     assert int(a.step) == int(b.step)
 
 
-@pytest.mark.parametrize("scan_rounds", [True, False],
-                         ids=["scanned", "eager"])
-def test_prefetched_executor_bitwise_parity(scan_rounds,
+@pytest.mark.parametrize("executor", ["scanned", "sharded"])
+def test_prefetched_executor_bitwise_parity(executor,
                                             no_implicit_transfers):
     cfg = _tiny_cfg()
+    mesh = make_client_mesh(3) if executor == "sharded" else None
     s_sync, m_sync, _, lab_sync, cls_sync = _run(
-        cfg, prefetch=False, scan_rounds=scan_rounds)
-    s_pf, m_pf, stats, lab_pf, cls_pf = _run(
-        cfg, prefetch=True, scan_rounds=scan_rounds)
+        cfg, prefetch=False, mesh=mesh)
+    s_pf, m_pf, stats, lab_pf, cls_pf = _run(cfg, prefetch=True, mesh=mesh)
 
     _assert_states_bitwise_equal(s_sync, s_pf)
     assert m_sync == m_pf                       # floats, exact
@@ -126,8 +126,7 @@ def test_prefetch_overlap_happens(no_implicit_transfers):
     must have done real build work and the consumer must not have eaten
     it all back waiting."""
     cfg = _tiny_cfg()
-    _, _, stats, _, _ = _run(cfg, prefetch=True, scan_rounds=True,
-                             rounds=4)
+    _, _, stats, _, _ = _run(cfg, prefetch=True, rounds=4)
     assert stats["rounds"] == 4
     assert stats["spec_build_s"] > 0.0
     assert stats["overlap_frac"] > 0.0
@@ -143,7 +142,7 @@ def test_pinned_active_set_mismatch_rebuilds_inline(no_implicit_transfers):
         with jax.transfer_guard("allow"):   # setup, see _run
             train, lab, cls = _rig(cfg)
             sys_ = SemiSFLSystem(cfg, n_clients_per_round=3,
-                                 scan_rounds=True, prefetch=prefetch)
+                                 prefetch=prefetch)
             state = sys_.init_state(0)
             ctrl = make_controller(cfg, 40, len(train.y))
         for r in range(3):

@@ -1,9 +1,10 @@
-"""Parity of the scan-compiled round executor against the eager per-step
-path: both drive the SAME step functions (`core/engine.py` builds one
-carry-style step and either jits it per-step or `lax.scan`s it via
-`core/scan.py`), so params/teacher/queue/metrics must match numerically
-over multiple rounds.  Also covers the LM-task scanned train phase
-(`launch/steps.py::make_scanned_train_phase`) and the `scan_phase`
+"""Parity of the scan-compiled round against a per-step reference loop:
+`core/engine.py` builds one carry-style step per phase and `lax.scan`s it
+via `core/scan.py`; the loop here jits the same unjitted steps one step
+at a time, so params/teacher/queue/metrics must match numerically over
+multiple rounds.  Also covers the one device-to-host read per round on
+the vmapped and client-sharded executors, the LM-task scanned train
+phase (`launch/steps.py::make_scanned_train_phase`) and the `scan_phase`
 builder itself."""
 import os
 from dataclasses import replace
@@ -18,7 +19,9 @@ from repro.core import engine
 from repro.core.engine import SemiSFLSystem, make_controller
 from repro.core.scan import scan_phase
 from repro.data import (Loader, client_loaders, make_image_dataset,
-                        train_test_split, uniform_partition)
+                        stack_client_batches, train_test_split,
+                        uniform_partition)
+from repro.launch.mesh import make_client_mesh
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
@@ -45,19 +48,71 @@ def _rig(cfg, seed=0):
     return train, test, lab, cls
 
 
-def _run(cfg, scan_rounds, rounds=2):
+def _executor(label):
+    """``SemiSFLSystem`` keywords of the vmapped executor, or of the
+    client-sharded one on the host's client mesh (one device here)."""
+    return {} if label == "scanned" else {"mesh": make_client_mesh(3)}
+
+
+def _run(cfg, rounds=2, k_s=None):
     # setup commits constants (PRNGKey, queue zeros) — allowed explicitly
     # so the ROUND LOOP below stays under the fixture's disallow net
     with jax.transfer_guard("allow"):
         train, test, lab, cls = _rig(cfg)
-        sys_ = SemiSFLSystem(cfg, n_clients_per_round=3,
-                             scan_rounds=scan_rounds)
+        sys_ = SemiSFLSystem(cfg, n_clients_per_round=3)
         state = sys_.init_state(0)
         ctrl = make_controller(cfg, 40, len(train.y))
     metrics = []
-    for _ in range(rounds):
+    for r in range(rounds):
+        if k_s is not None:
+            ctrl.k_s = k_s[r]
         state, m = sys_.run_round(state, lab, cls, ctrl)
         metrics.append((m.f_s, m.f_u, m.mask_rate))
+    return state, metrics
+
+
+def _reference(cfg, k_s):
+    """The rounds of ``_run`` as a per-step loop: the engine's unjitted
+    step functions, jitted one step at a time, drive K_s supervised steps,
+    the broadcast, K_u cross-entity steps and FedAvg, drawing the same
+    batches and clients in the same order as ``run_round``."""
+    n = 3
+    with jax.transfer_guard("allow"):   # setup, see _run
+        _, _, lab, cls = _rig(cfg)
+        sys_ = SemiSFLSystem(cfg, n_clients_per_round=n)
+        state = sys_.init_state(0)
+        one = jnp.ones((), jnp.int32)
+    supervised = jax.jit(sys_._supervised_step_fn)
+    semi = jax.jit(sys_._semi_step_fn)
+    select = np.random.RandomState(0)     # init_state's selection seed
+    stack = lambda t: jnp.broadcast_to(t, (n,) + t.shape)
+    mean = lambda t: t.mean(axis=0)
+    metrics = []
+    for ks in k_s:
+        f_s = []
+        for _ in range(ks):
+            x, y = lab.next()
+            state, loss = supervised(state, (jnp.asarray(x), jnp.asarray(y)))
+            f_s.append(_get(loss))
+        active = list(select.choice(len(cls), size=n, replace=False))
+        carry = (jax.tree.map(stack, state.params["bottom"]),
+                 jax.tree.map(stack, state.teacher["bottom"]),
+                 state.params["top"], state.params["proj"], state.teacher,
+                 state.queue, state.rng, state.step)
+        f_u, mask = [], []
+        for _ in range(cfg.semisfl.k_u):
+            xu, _ = stack_client_batches(cls, active)
+            carry, (loss, _h, mask_rate) = semi(carry, jnp.asarray(xu))
+            f_u.append(_get(loss))
+            mask.append(_get(mask_rate))
+        bottoms, t_bottoms, top, proj, teacher, queue, rng, step = carry
+        state = state._replace(
+            params={"bottom": jax.tree.map(mean, bottoms), "top": top,
+                    "proj": proj},
+            teacher=dict(teacher, bottom=jax.tree.map(mean, t_bottoms)),
+            queue=queue, rng=rng, round=state.round + one, step=step)
+        metrics.append((float(np.mean(f_s)), float(np.mean(f_u)),
+                        float(np.mean(mask))))
     return state, metrics
 
 
@@ -76,9 +131,10 @@ def _max_abs_diff(a, b):
 
 
 def test_scanned_round_matches_eager_two_rounds(no_implicit_transfers):
+    """Two scanned rounds equal the per-step reference loop."""
     cfg = _tiny_cfg()
-    s_eager, m_eager = _run(cfg, scan_rounds=False)
-    s_scan, m_scan = _run(cfg, scan_rounds=True)
+    s_eager, m_eager = _reference(cfg, (3, 3))
+    s_scan, m_scan = _run(cfg)
 
     assert _max_abs_diff(s_eager.params, s_scan.params) < 1e-5
     assert _max_abs_diff(s_eager.teacher, s_scan.teacher) < 1e-5
@@ -96,35 +152,25 @@ def test_scanned_round_matches_eager_two_rounds(no_implicit_transfers):
 
 def test_scanned_round_same_when_ks_adapts(no_implicit_transfers):
     """The scanned executor retraces (one compile per distinct K_s) but
-    stays numerically equal to eager when Eq. (10) shrinks K_s."""
+    stays numerically equal to the per-step reference loop when Eq. (10)
+    shrinks K_s."""
     cfg = _tiny_cfg()
-    results = {}
-    for scan in (False, True):
-        with jax.transfer_guard("allow"):   # setup, see _run
-            train, _, lab, cls = _rig(cfg)
-            sys_ = SemiSFLSystem(cfg, n_clients_per_round=3,
-                                 scan_rounds=scan)
-            state = sys_.init_state(0)
-            ctrl = make_controller(cfg, 40, len(train.y))
-        for r in range(2):
-            ctrl.k_s = 3 - r        # forced shrink: 3 then 2
-            state, _ = sys_.run_round(state, lab, cls, ctrl)
-        results[scan] = state
-    assert _max_abs_diff(results[False].params, results[True].params) < 1e-5
+    s_ref, _ = _reference(cfg, (3, 2))     # forced shrink: 3 then 2
+    s_scan, _ = _run(cfg, k_s=(3, 2))
+    assert _max_abs_diff(s_ref.params, s_scan.params) < 1e-5
     # step counter is cumulative over the ACTUAL k_s values, no drift
-    assert int(_get(results[True].step)) == (3 + 2) + (2 + 2)
+    assert int(_get(s_scan.step)) == (3 + 2) + (2 + 2)
 
 
 @pytest.mark.parametrize(
-    "scan_rounds,prefetch", [(True, False), (True, True), (False, False),
-                             (False, True)],
-    ids=["scanned", "scanned-prefetched", "eager", "eager-prefetched"])
-def test_round_reads_the_chip_once(scan_rounds, prefetch, monkeypatch,
+    "executor,prefetch", [("scanned", False), ("scanned", True),
+                          ("sharded", False), ("sharded", True)],
+    ids=["scanned", "scanned-prefetched", "sharded", "sharded-prefetched"])
+def test_round_reads_the_chip_once(executor, prefetch, monkeypatch,
                                    no_implicit_transfers):
-    """A scanned round makes one device-to-host read, at its end, on
-    both the synchronous and the prefetched executor; the eager path
-    reads each step's losses, K_s + 2 K_u reads a round.  Reads are
-    counted as ``semisfl.sync`` spans of the driver thread."""
+    """A round makes one device-to-host read, at its end, on the vmapped
+    and the client-sharded executor, synchronous and prefetched.  Reads
+    are counted as ``semisfl.sync`` spans of the driver thread."""
     names = []
     real_span = engine.span
 
@@ -136,37 +182,36 @@ def test_round_reads_the_chip_once(scan_rounds, prefetch, monkeypatch,
     cfg = _tiny_cfg()
     with jax.transfer_guard("allow"):   # setup, see _run
         train, _, lab, cls = _rig(cfg)
-        sys_ = SemiSFLSystem(cfg, n_clients_per_round=3,
-                             scan_rounds=scan_rounds, prefetch=prefetch)
+        sys_ = SemiSFLSystem(cfg, n_clients_per_round=3, prefetch=prefetch,
+                             **_executor(executor))
         state = sys_.init_state(0)
         ctrl = make_controller(cfg, 40, len(train.y))
-    k_u = cfg.semisfl.k_u
     for k_s in (3, 2):                  # the second round adapts K_s
         ctrl.k_s = k_s
         names.clear()
         state, _ = sys_.run_round(state, lab, cls, ctrl)
-        reads = names.count("sync")
-        assert reads == (1 if scan_rounds else k_s + 2 * k_u), names
-        if scan_rounds:                 # the read comes after every dispatch
-            assert names[-1] == "sync", names
+        assert names.count("sync") == 1, names
+        assert names[-1] == "sync", names   # after every dispatch
     sys_.close()
 
 
 def test_controller_adaptation_same_on_every_executor(no_implicit_transfers):
     """Eq. (10) shrinks K_s on its own (observation period and window of
     one round, K_min 1): the controller is fed the round's read-back
-    losses, so the scanned executors, synchronous and prefetched, give
-    the same RoundMetrics and state bit for bit, and the eager path the
-    same K_s history with numerically equal metrics."""
+    losses, so the vmapped executor, synchronous and prefetched, gives
+    the same RoundMetrics and state bit for bit, and the client-sharded
+    executor (one device) the same K_s history with numerically equal
+    metrics."""
     cfg = _tiny_cfg()
     cfg = replace(cfg, semisfl=replace(cfg.semisfl, observation_period=1,
                                        adaptation_window=1, beta=1.0))
     runs = {}
-    for scan, prefetch in ((True, False), (True, True), (False, False)):
+    for executor, prefetch in (("scanned", False), ("scanned", True),
+                               ("sharded", False)):
         with jax.transfer_guard("allow"):   # setup, see _run
             train, _, lab, cls = _rig(cfg)
             sys_ = SemiSFLSystem(cfg, n_clients_per_round=3,
-                                 scan_rounds=scan, prefetch=prefetch)
+                                 prefetch=prefetch, **_executor(executor))
             state = sys_.init_state(0)
             ctrl = make_controller(cfg, 40, len(train.y))
         metrics = []
@@ -174,21 +219,21 @@ def test_controller_adaptation_same_on_every_executor(no_implicit_transfers):
             state, m = sys_.run_round(state, lab, cls, ctrl)
             metrics.append((m.f_s, m.f_u, m.mask_rate, m.k_s))
         sys_.close()
-        runs[scan, prefetch] = state, metrics, list(ctrl.history)
-    s_sync, m_sync, k_sync = runs[True, False]
-    s_pf, m_pf, k_pf = runs[True, True]
-    s_eager, m_eager, k_eager = runs[False, False]
+        runs[executor, prefetch] = state, metrics, list(ctrl.history)
+    s_sync, m_sync, k_sync = runs["scanned", False]
+    s_pf, m_pf, k_pf = runs["scanned", True]
+    s_shard, m_shard, k_shard = runs["sharded", False]
     assert len(set(k_sync)) > 1, k_sync             # K_s adapted
-    assert k_sync == k_pf == k_eager
+    assert k_sync == k_pf == k_shard
     assert m_sync == m_pf                            # floats, exact
     same = jax.tree.map(
         lambda a, b: bool(np.array_equal(_get(a), _get(b))),
         (s_sync.params, s_sync.teacher, s_sync.queue),
         (s_pf.params, s_pf.teacher, s_pf.queue))
     assert all(jax.tree.leaves(same)), same
-    for a, b in zip(m_eager, m_sync):
+    for a, b in zip(m_shard, m_sync):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
-    assert _max_abs_diff(s_eager.params, s_sync.params) < 1e-5
+    assert _max_abs_diff(s_shard.params, s_sync.params) < 1e-5
 
 
 def test_scan_phase_builder_matches_python_loop():

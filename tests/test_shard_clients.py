@@ -16,6 +16,7 @@ import subprocess
 import sys
 import textwrap
 
+import pytest
 from jax.sharding import PartitionSpec as P
 
 SCRIPT = textwrap.dedent("""
@@ -129,7 +130,7 @@ SCRIPT = textwrap.dedent("""
         xus, _ = stack_client_batches_many(
             cls, list(range(n_active)), 2, shardings=sys_._stack_shardings)
         jaxpr = jax.make_jaxpr(
-            lambda c, x: sys_.semi_phase_sharded(c, x))(carry, xus)
+            lambda c, x: sys_.semi_phase(c, x))(carry, xus)
         return collect(jaxpr.jaxpr, {})
 
     c8, c16 = counts(8), counts(16)
@@ -159,6 +160,49 @@ def test_sharded_executor_multidevice():
 # ---------------------------------------------------------------------------
 # single-device units: mesh helpers + the new PartitionSpecs
 # ---------------------------------------------------------------------------
+
+
+def _primitives(jaxpr, acc):
+    """Names of every primitive in ``jaxpr`` and its sub-jaxprs."""
+    for eqn in jaxpr.eqns:
+        acc.add(eqn.primitive.name)
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                if hasattr(sub, "jaxpr"):
+                    _primitives(sub.jaxpr, acc)
+                elif hasattr(sub, "eqns"):
+                    _primitives(sub, acc)
+    return acc
+
+
+@pytest.mark.parametrize("use_clustering", [True, False])
+def test_the_vmapped_step_has_no_collective(use_clustering):
+    """The one cross-entity step, built for the vmapped executor, holds
+    none of the client-sharded executor's collectives: each of its
+    executor helpers is the identity there."""
+    from dataclasses import replace
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.configs import smoke_config
+    from repro.core.engine import SemiSFLSystem
+
+    cfg = smoke_config("paper-cnn")
+    cfg = replace(cfg, image_size=8, cnn_channels=(4, 8),
+                  semisfl=replace(cfg.semisfl, k_u=2, queue_len=32))
+    sys_ = SemiSFLSystem(cfg, n_clients_per_round=2,
+                         use_clustering=use_clustering)
+    state = sys_.init_state(0)
+    bottoms, t_bottoms = sys_.broadcast(state)
+    carry = (bottoms, t_bottoms, state.params["top"], state.params["proj"],
+             state.teacher, state.queue, state.rng, state.step)
+    xu = jnp.zeros((2, 8, 8, 8, 3), jnp.float32)
+    names = _primitives(jax.make_jaxpr(sys_._semi_step_fn)(carry, xu).jaxpr,
+                        set())
+    assert "dot_general" in names or "conv_general_dilated" in names
+    assert not {n for n in names if any(
+        t in n for t in ("psum", "all_gather", "axis_index"))}, names
 
 
 def test_mesh_axes_two_and_three_axis():

@@ -164,10 +164,10 @@ def _rig(seed=0):
     return cfg, train, lab, cls
 
 
-def _run_round(wire_format, scan_rounds=None, seed=0):
+def _run_round(wire_format, mesh=None, seed=0):
     cfg, train, lab, cls = _rig(seed)
-    sys_ = SemiSFLSystem(cfg, n_clients_per_round=3, scan_rounds=scan_rounds,
-                        wire_format=wire_format)
+    sys_ = SemiSFLSystem(cfg, n_clients_per_round=3, mesh=mesh,
+                         wire_format=wire_format)
     state = sys_.init_state(seed)
     ctrl = make_controller(cfg, 40, len(train.y))
     state, m = sys_.run_round(state, lab, cls, ctrl)
@@ -200,10 +200,14 @@ def test_compressed_round_trains_and_differs_from_fp32():
 
 
 def test_wire_eager_vs_scanned_parity():
-    s_eager, _ = _run_round("int8+topk0.5", scan_rounds=False)
-    s_scan, _ = _run_round("int8+topk0.5", scan_rounds=True)
-    for a, b in zip(jax.tree.leaves(s_eager.params),
-                    jax.tree.leaves(s_scan.params)):
+    """A compressed round on the vmapped executor equals the same round on
+    the client-sharded executor (one-device mesh): both run the one
+    cross-entity step and the one top-k FedAvg program."""
+    from repro.launch.mesh import make_client_mesh
+    s_vmap, _ = _run_round("int8+topk0.5")
+    s_shard, _ = _run_round("int8+topk0.5", mesh=make_client_mesh(3))
+    for a, b in zip(jax.tree.leaves(s_vmap.params),
+                    jax.tree.leaves(s_shard.params)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=1e-5, rtol=1e-5)
 

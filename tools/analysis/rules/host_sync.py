@@ -3,7 +3,7 @@
 ``int()``/``float()``/``bool()``/``.item()``/``np.asarray`` on a device
 value blocks until the device catches up.  Inside a jitted function it
 is worse: under trace it either fails (ConcretizationTypeError) or — for
-code that only *sometimes* traces, like the engine's eager fallback
+code that only *sometimes* traces, like a per-step loop's eager
 path — silently serialises every step.  PR 3's
 ``RandomState(int(state.round))`` cost a full device sync per round
 before it was caught by a profile, not by review.
@@ -197,9 +197,9 @@ class HostSyncInHotPath(Rule):
                         f".item() inside hot function '{name}' — forces a "
                         "host sync; keep the value on device")
 
-        # part B: the round loop.  Casts here run per round (or per step,
-        # in the eager fallback) — they must go through an explicit
-        # host-read helper so the sync is visible and transfer-guard-safe.
+        # part B: the round loop.  Casts here run per round — they must
+        # go through an explicit host-read helper so the sync is visible
+        # and transfer-guard-safe.
         for node in ast.walk(module.tree):
             if not isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
                 continue
