@@ -66,8 +66,8 @@ def main(argv=None) -> int:
         side, metrics=side["metrics"][:harness.N_COMPARED])
     for i, seed in enumerate(seeds(args.seeds)):
         data = traffic.make_traffic(mix, cfg, seed)
-        feed = harness.make_feed(sys_, cell, mix, data, seed)
-        prog = harness.compared_rounds(sys_, feed, cell)
+        feed = harness.make_feed(sys_, cell, cfg, mix, data, seed)
+        prog = harness.compared_rounds(sys_, feed)
         del feed
         ref = harness.reference_side(cell, cfg, mix, data, seed, **every)
         rows = [("program", prog)]

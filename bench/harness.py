@@ -10,15 +10,36 @@ per-layer metric is a file of its own, found by the name
     bench/workloads/<cell>.json      configuration and traffic names, chips,
                                      the compared state's start, limits
     bench/configs/<config>.json      the configuration as run
+    bench/families/<family>.py       its model family (the config names it)
     bench/reference/<name>.py        its plain reference (the config names it)
     bench/traffic/<mix>.json         the traffic mix the generator reads
     bench/metrics/<metric>.py        a per-layer metric's reader
 
-A later cell, configuration or metric is new files plus an entry in
-``BENCHMARK.json``; nothing here needs an edit.
+A model family module holds all that depends on what kind of model a
+configuration is, in six functions:
+
+    make_dataset(cfg, mix, seed, n)   n samples and labels from the seed
+                                      (``traffic.make_traffic``)
+    model_keys(sys_)                  the model's configuration keys as the
+                                      built program has them
+                                      (``program_config_gaps``)
+    scale_teacher(teacher, scale)     the compared state's confident
+                                      teacher (``make_feed``)
+    labeled_stack(cfg, mix, k)        a zero host stack of k labeled batches
+                                      (the warm-up of ``run_cell``)
+    forward_flops(cfg, mix)           FLOPs of one sample's forward pass
+    train_flops(cfg, mix)             and of its training step (``flops``)
+
+A later cell, configuration, model family or metric is new files plus an
+entry in ``BENCHMARK.json``; nothing here needs an edit.  After the
+window, a reader finds every round's ``RoundMetrics`` as a dict in
+``ctx["round_metrics"]``, so a counter the program adds there needs only
+a reader.
 """
 from __future__ import annotations
 
+import dataclasses
+import functools
 import gc
 import importlib.util
 import json
@@ -51,8 +72,23 @@ def cell_names(root=BENCH) -> list:
 def load_cell(name: str, root=BENCH) -> tuple:
     """(cell, configuration, traffic mix) of the named cell."""
     cell = _json(root, "workloads", name)
-    return cell, _json(root, "configs", cell["config"]), \
-        _json(root, "traffic", cell["traffic"])
+    cfg = _json(root, "configs", cell["config"])
+    family_file(cell["config"], cfg, root)
+    return cell, cfg, _json(root, "traffic", cell["traffic"])
+
+
+def family_file(name: str, cfg: dict, root=BENCH) -> Path:
+    """The file of the model family that configuration ``name`` names."""
+    fam = cfg.get("family")
+    path = Path(root) / "families" / f"{fam}.py"
+    if fam is None:
+        raise FileNotFoundError(
+            f"configuration {name!r} names no model family: it needs a "
+            f"'family' key, the name of a file {path.parent}/<family>.py")
+    if not path.is_file():
+        raise FileNotFoundError(f"configuration {name!r} names model family "
+                                f"{fam!r}, but there is no {path}")
+    return path
 
 
 def _module(path: Path):
@@ -71,6 +107,18 @@ def metric_readers(root=BENCH) -> dict:
 
 def reference_module(cfg: dict, root=BENCH):
     return _module(Path(root) / "reference" / f"{cfg['reference']}.py")
+
+
+def family(cfg: dict, root=BENCH):
+    """The model family module the configuration names (``load_cell`` has
+    checked that its file is there); loaded once, as the window's FLOP
+    counts ask for it every round."""
+    return _family_at(str(root), cfg["family"])
+
+
+@functools.lru_cache(maxsize=None)
+def _family_at(root: str, name: str):
+    return _module(Path(root) / "families" / f"{name}.py")
 
 
 # ------------------------------------------------------------ helpers
@@ -110,31 +158,13 @@ def _change_norms(tree, start) -> dict:
     return _leaf_norms(diff(tree, start))
 
 
-def _scale_teacher(teacher, scale: float):
-    """The compared state's confident teacher: its classifier's weights
-    scaled, so that pseudo-labels clear tau from the first round."""
-    import jax
-    out = dict(teacher, top=dict(teacher["top"],
-                                 cls=dict(teacher["top"]["cls"])))
-    out["top"]["cls"]["w"] = jax.jit(lambda w: w * scale)(
-        teacher["top"]["cls"]["w"])
-    return out
-
-
-def program_config_gaps(sys_, cfg: dict) -> list:
+def program_config_gaps(sys_, cfg: dict, root=BENCH) -> list:
     """Keys where the program runs something else than the configuration
-    file states."""
-    import jax
+    file states: the model's keys, as its family reads them, and
+    SemiSFL's and the optimiser's."""
     pc, s = sys_.cfg, sys_.cfg.semisfl
-    shapes = jax.eval_shape(sys_.model.init, jax.random.PRNGKey(0))
-    kernels = sorted({n for part in ("bottom", "top")
-                      for c in shapes[part]["convs"] for n in c["w"].shape[:2]})
     seen = {
-        "conv_kernel": kernels[0] if len(kernels) == 1 else kernels,
-        "pool_after": [i + 1 for i, p in enumerate(sys_.model.pool_at) if p],
-        "cnn_channels": list(pc.cnn_channels), "cnn_fc": list(pc.cnn_fc),
-        "image_size": pc.image_size, "split_layer": pc.split_layer,
-        "num_classes": pc.num_classes, "cnn_dropout": pc.cnn_dropout,
+        **family(cfg, root).model_keys(sys_),
         "dtype": pc.dtype, "queue_len": s.queue_len, "proj_dim": s.proj_dim,
         "proj_hidden": s.proj_hidden, "proj_head": s.proj_head,
         "temperature": s.temperature, "tau": s.confidence_threshold,
@@ -169,7 +199,7 @@ def reachable_k_s(cfg: dict, k_min: int, n_rounds: int) -> list:
 
 # ------------------------------------------------------------ the run
 
-def make_system(cfg: dict, mix: dict, mesh=None):
+def make_system(cfg: dict, mix: dict, mesh=None, root=BENCH):
     """The system under test, as ``run_training`` builds it, and the keys
     where it departs from the configuration file."""
     from repro.launch.train import build_system, train_config
@@ -180,46 +210,51 @@ def make_system(cfg: dict, mix: dict, mesh=None):
         kw.update(mesh=mesh, shard_clients=True)
     sys_ = build_system("semisfl", pcfg, n_clients_per_round=mix["n_active"],
                         **kw)
-    return sys_, program_config_gaps(sys_, {**cfg, **mix})
+    return sys_, program_config_gaps(sys_, {**cfg, **mix}, root)
 
 
-def make_feed(sys_, cell: dict, mix: dict, data, seed: int) -> dict:
+def make_feed(sys_, cell: dict, cfg: dict, mix: dict, data, seed: int,
+              root=BENCH) -> dict:
     """The compared state and the window's feed, from the seed: the
-    program's own initial state with a confident teacher, its loaders,
-    controller and client-selection RandomState."""
+    program's own initial state with a confident teacher, the parameters
+    and teacher that state starts from (``start``, which
+    ``compared_rounds`` takes), its loaders, controller and
+    client-selection RandomState."""
+    import jax
+    import jax.numpy as jnp
+
     from repro.core.engine import make_controller
     from repro.data import Loader, client_loaders
     from repro.data.synthetic import Dataset
+    scale_teacher = family(cfg, root).scale_teacher
+    scale = cell["start"]["teacher_scale"]
     state = sys_.init_state(seed)
-    state = state._replace(teacher=_scale_teacher(
-        state.teacher, cell["start"]["teacher_scale"]))
+    start = jax.tree.map(jnp.copy, state.params)
+    state = state._replace(teacher=scale_teacher(state.teacher, scale))
     train = Dataset(data.train.x, data.train.y)
-    return {"state": state,
+    return {"state": state, "start": (start, scale_teacher(start, scale)),
             "ctrl": make_controller(sys_.cfg, mix["n_labeled"],
                                     mix["n_train"]),
             "lab": Loader(train, data.labeled, mix["labeled_batch"], seed),
-            "cls": client_loaders(train, data.parts, mix["client_batch"],
-                                  seed + 1),
+            "clients": client_loaders(train, data.parts,
+                                      mix["client_batch"], seed + 1),
             "sel": np.random.RandomState(seed)}
 
 
-def compared_rounds(sys_, feed: dict, cell: dict) -> dict:
+def compared_rounds(sys_, feed: dict) -> dict:
     """Drive the set-up rounds through ``run_round`` and read what the
     comparison needs: each round's losses, the momentum after the first
     round, and the change over the compared rounds (``change``) and over
     all set-up rounds (``change_all``); ``feed["state"]`` moves on to the
     last round's."""
-    import jax
-    import jax.numpy as jnp
     state = feed["state"]
-    start = jax.tree.map(jnp.copy, state.params)
-    teacher0 = _scale_teacher(start, cell["start"]["teacher_scale"])
+    start, teacher0 = feed.pop("start")
     change = lambda st: {"params": _change_norms(st.params, start),
                          "teacher": _change_norms(st.teacher, teacher0)}
     prog = {"metrics": [], "round_s": []}
     for r in range(N_SETUP):
         t0 = time.perf_counter()
-        state, m = sys_.run_round(state, feed["lab"], feed["cls"],
+        state, m = sys_.run_round(state, feed["lab"], feed["clients"],
                                   feed["ctrl"], rng_np=feed["sel"])
         prog["round_s"].append(time.perf_counter() - t0)
         prog["metrics"].append((m.f_s, m.f_u, m.mask_rate))
@@ -271,11 +306,11 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     cell, cfg, mix = load_cell(name, root)
     setup_compile_cache()
     clock = CompileClock()
-    data = traffic.make_traffic(mix, cfg, seed)
+    data = traffic.make_traffic(mix, cfg, seed, root)
     mesh = mesh_fn(mix) if mesh_fn else None
-    sys_, gaps = make_system(cfg, mix, mesh)
-    feed = make_feed(sys_, cell, mix, data, seed)
-    prog = compared_rounds(sys_, feed, cell)
+    sys_, gaps = make_system(cfg, mix, mesh, root)
+    feed = make_feed(sys_, cell, cfg, mix, data, seed, root)
+    prog = compared_rounds(sys_, feed)
     state, ctrl = feed["state"], feed["ctrl"]
     test_x, test_y = data.test.x, data.test.y
     sys_.evaluate(state, test_x, test_y)           # both eval batch shapes
@@ -286,11 +321,9 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     warm = [k for k in reachable_k_s({**cfg, **mix}, ctrl.k_min, bound)
             if k != ctrl.k_s]
     for k in warm:
-        shape = (k, mix["labeled_batch"], cfg["image_size"],
-                 cfg["image_size"], 3)
         scratch = jax.tree.map(jnp.copy, state)
         out = sys_.supervised_phase(scratch, sys_._sup_put(
-            np.zeros(shape, np.float32), np.zeros(shape[:2], np.int32)))
+            *family(cfg, root).labeled_stack(cfg, mix, k)))
         jax.block_until_ready(out)
         del scratch, out
     jax.block_until_ready(state)
@@ -306,11 +339,12 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
         opts.enable_hlo_proto = False
         jax.profiler.start_trace(str(tdir), profiler_options=opts)
     span = jax.profiler.TraceAnnotation
-    lab, cls, sel = feed["lab"], feed["cls"], feed["sel"]
+    lab, cls, sel = feed["lab"], feed["clients"], feed["sel"]
     del feed
     t_window = time.perf_counter()
     setup_s = t_window - t_start
     round_s, eval_s, k_hist, fails, samples, work = [], [], [], 0, 0, 0
+    rounds = []
     with span("bench.window"):
         r = 0
         while True:
@@ -318,17 +352,18 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
             k_s = ctrl.k_s
             with span("bench.round"):
                 state, m = sys_.run_round(state, lab, cls, ctrl, rng_np=sel)
+            rounds.append(m)
             if r % mix["eval_every"] == 0:
                 te = time.perf_counter()
                 with span("bench.eval"):
                     sys_.evaluate(state, test_x, test_y)
                 eval_s.append(time.perf_counter() - te)
-                work += flops.eval_flops(cfg, len(test_y))
+                work += flops.eval_flops(cfg, len(test_y), mix, root)
             t1 = time.perf_counter()
             round_s.append(t1 - t0)
             k_hist.append(k_s)
             samples += flops.round_samples(mix, cfg, k_s)
-            work += flops.round_flops(cfg, mix, k_s)
+            work += flops.round_flops(cfg, mix, k_s, root)
             fails += not (math.isfinite(m.f_s) and math.isfinite(m.f_u))
             r += 1
             if t1 - t_window >= seconds:
@@ -373,6 +408,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
            "setup_s": setup_s,
            "chips": len(devices), "round_s": round_s, "eval_s": eval_s,
            "k_s": k_hist, "model_flops": work, "samples": samples,
+           "round_metrics": [dataclasses.asdict(m) for m in rounds],
            "device_kind": device.device_kind, "trace": None}
     if trace:
         from bench import trace as tr

@@ -17,10 +17,10 @@ for p in (str(REPO), str(REPO / "src")):
     if p not in sys.path:
         sys.path.insert(0, p)
 
-TINY = dict(arch="bench-tiny", cnn_channels=[4, 8], pool_after=[1, 2],
-            cnn_fc=[16], image_size=16, num_classes=5, split_layer=2,
-            queue_len=64, proj_dim=8, proj_hidden=16, observation_period=2,
-            adaptation_window=1)
+TINY = dict(arch="bench-tiny", family="cnn", cnn_channels=[4, 8],
+            pool_after=[1, 2], cnn_fc=[16], image_size=16, num_classes=5,
+            split_layer=2, queue_len=64, proj_dim=8, proj_hidden=16,
+            observation_period=2, adaptation_window=1)
 # The first round on the CPU, seeds 1, 2, 5, 2**31 + 52, 2147490002 and
 # 3e9: the program reads loss <= 9.7e-8, grad <= 2.7e-7, change <= 7.9e-7
 # against the reference; the control reads loss >= 4.8e-7, grad >= 7.2e-6,
@@ -48,7 +48,8 @@ def make_tiny_root(dest: Path) -> Path:
     """A copy of the benchmark's files with one more cell, ``tiny``,
     added as files and one entry; returns the copy's ``bench`` dir."""
     root = dest / "bench"
-    for sub in ("configs", "traffic", "workloads", "metrics", "reference"):
+    for sub in ("configs", "families", "traffic", "workloads", "metrics",
+                "reference"):
         shutil.copytree(BENCH / sub, root / sub)
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
     cfg = json.loads((root / "configs/paper-vgg16.json").read_text())

@@ -59,9 +59,9 @@ def test_the_control_fails_the_comparison(tiny_root, monkeypatch):  # noqa: F811
     seed = 2 ** 31 + 7
     compared = harness.compared_rounds
 
-    def control(sys_, feed, cell_):
-        prog = compared(sys_, feed, cell_)      # the window starts as usual
-        side = harness.reference_side(cell_, cfg, mix,
+    def control(sys_, feed):
+        prog = compared(sys_, feed)            # the window starts as usual
+        side = harness.reference_side(cell, cfg, mix,
                                       traffic.make_traffic(mix, cfg, seed),
                                       seed, root=tiny_root, precision="high")
         return dict(prog, **{k: side[k] for k in ("metrics", "grad",

@@ -3,6 +3,7 @@ per-layer metrics by name, the yardstick's arithmetic, the result line,
 and the refusal to run without a TPU."""
 from __future__ import annotations
 
+import hashlib
 import json
 import math
 import os
@@ -12,8 +13,10 @@ import subprocess
 import sys
 from pathlib import Path
 
+import numpy as np
 import pytest
-from bench_tinycell import BENCH, REPO, run_tiny, tiny_root  # noqa: F401
+from bench_tinycell import (BENCH, REPO, make_tiny_root,  # noqa: F401
+                            register_tiny, run_tiny, tiny_root)
 
 from bench import compare, flops, harness, peaks
 
@@ -43,7 +46,7 @@ def test_every_cell_config_and_metric_is_found_by_name():
 
 
 def test_a_new_cell_is_listed_with_no_other_edit(tmp_path):
-    for sub in ("configs", "traffic", "workloads"):
+    for sub in ("configs", "families", "traffic", "workloads"):
         shutil.copytree(BENCH / sub, tmp_path / sub)
     cell = json.loads((BENCH / "workloads/cnn-default.json").read_text())
     (tmp_path / "workloads/cnn-extra.json").write_text(json.dumps(cell))
@@ -71,6 +74,76 @@ def test_benchmark_json_keeps_to_its_schema():
         assert set(m.get("workloads", cells)) <= cells
     for w in b["workloads"]:
         assert len(w["why"]) <= 200 and w["chips"] in (1, 4)
+
+
+@pytest.mark.parametrize("fault,message", [
+    (None, r"configuration 'tiny' names no model family: it needs a "
+           r"'family' key, the name of a file .*/families/<family>\.py"),
+    ("no-such", r"configuration 'tiny' names model family 'no-such', but "
+                r"there is no .*/families/no-such\.py")])
+def test_a_configuration_must_name_a_family_file(tmp_path, fault, message):
+    root = make_tiny_root(tmp_path)
+    path = root / "configs/tiny.json"
+    cfg = json.loads(path.read_text())
+    if fault is None:
+        del cfg["family"]
+    else:
+        cfg["family"] = fault
+    path.write_text(json.dumps(cfg))
+    with pytest.raises(FileNotFoundError, match=message):
+        harness.load_cell("tiny", root)
+
+
+# ------------------------------------------------- what the families keep
+# Read on the parent commit of the per-family split, before the CNN code
+# moved into bench/families/cnn.py: the default traffic at seed
+# 2147483659, the round's FLOPs at K_s 15 and 3, and 400 evaluations.
+
+PARENT = {
+    "paper-cnn": {
+        "traffic": "c5616c926ed65b60adb0133d3aca25f107a4cc0413b7c9e0"
+                   "ca898e4f1155ab6f",
+        "round_flops": {15: 45789224960, 3: 23809138688},
+        "eval_flops": 5840384000},
+    "vgg16-image100": {
+        "traffic": "a5fb5a9a8a50115e75cf711f63670b2c221cf07741f40201"
+                   "e317142ead4a971b",
+        "round_flops": {15: 38815099535360, 3: 20183850500096},
+        "eval_flops": 4858934681600},
+}
+
+
+def _config_and_mix(name: str) -> tuple:
+    return (json.loads((BENCH / f"configs/{name}.json").read_text()),
+            json.loads((BENCH / "traffic/default.json").read_text()))
+
+
+@pytest.mark.parametrize("name", sorted(PARENT))
+def test_the_traffic_arrays_are_the_parents(name):
+    from bench import traffic
+    cfg, mix = _config_and_mix(name)
+    data = traffic.make_traffic(mix, cfg, 2147483659)
+    h = hashlib.sha256()
+    for a in (data.train.x, data.train.y, data.test.x, data.test.y,
+              data.labeled, *data.parts):
+        a = np.ascontiguousarray(a)
+        h.update(f"{a.dtype}{a.shape}".encode())
+        h.update(a.tobytes())
+    assert h.hexdigest() == PARENT[name]["traffic"]
+
+
+@pytest.mark.parametrize("name", sorted(PARENT))
+def test_the_round_and_eval_flops_are_the_parents(name):
+    cfg, mix = _config_and_mix(name)
+    assert {k: flops.round_flops(cfg, mix, k) for k in (15, 3)} == \
+        PARENT[name]["round_flops"]
+    assert flops.eval_flops(cfg, 400) == PARENT[name]["eval_flops"]
+
+
+@pytest.mark.parametrize("name", sorted(PARENT))
+def test_the_built_program_runs_what_the_configuration_states(name):
+    cfg, mix = _config_and_mix(name)
+    assert harness.make_system(cfg, mix)[1] == []
 
 
 # ------------------------------------------------------------- arithmetic
@@ -199,6 +272,85 @@ def test_window_compiles_nothing_though_k_s_adapts(tiny_run):
     assert info["warmed_k_s"] == [6, 4, 3]
     assert info["rounds"] == len(info["k_s_per_round"])
     assert 0 < min(info["compared_anchor_share"])
+
+
+# ------------------------------------- a family and a reader, as new files
+
+COUNTED = ("make_dataset", "model_keys", "scale_teacher", "labeled_stack",
+           "forward_flops", "train_flops")
+# a second family, written as a file of the copied tree only: the CNN
+# family's code, each of its six functions noting that it was called
+SEEN_CALLS = """
+SEEN = set()
+
+
+def _noted(fn):
+    def call(*args, **kw):
+        SEEN.add(fn.__name__)
+        return fn(*args, **kw)
+    return call
+
+
+for _name in {names!r}:
+    globals()[_name] = _noted(globals()[_name])
+"""
+MASK_READER = """
+def read(ctx):
+    rounds = ctx.get("round_metrics")
+    if not rounds:
+        return None
+    return 100.0 * sum(r["mask_rate"] for r in rounds) / len(rounds)
+"""
+
+
+@pytest.fixture(scope="module")
+def plugged(tmp_path_factory):
+    """A run of the tiny cell whose configuration names ``cnn_copy``, a
+    family that is a new file in the copied tree, which also holds a new
+    per-layer reader of the rounds' metrics; and the run's reader ctx."""
+    register_tiny()
+    root = make_tiny_root(tmp_path_factory.mktemp("plugged"))
+    (root / "families/cnn_copy.py").write_text(
+        (root / "families/cnn.py").read_text()
+        + SEEN_CALLS.format(names=COUNTED))
+    (root / "metrics/rounds.mask_rate.py").write_text(MASK_READER)
+    path = root / "configs/tiny.json"
+    path.write_text(json.dumps({**json.loads(path.read_text()),
+                                "family": "cnn_copy"}))
+    seen = {}
+    with pytest.MonkeyPatch.context() as mp:
+        metrics_for = harness.metrics_for
+
+        def keep_ctx(name, ctx, trace, root_):
+            seen.update(ctx)
+            return metrics_for(name, ctx, trace, root_)
+
+        mp.setattr(harness, "metrics_for", keep_ctx)
+        result = run_tiny(root)
+    return root, result, seen
+
+
+def test_a_family_added_as_a_file_runs_the_tiny_cell_correct(plugged):
+    root, r, ctx = plugged
+    fam = harness.family(ctx["cfg"], root)
+    assert Path(fam.__file__) == root / "families/cnn_copy.py"
+    assert fam.SEEN == set(COUNTED)
+    assert r["correct"] is True and r["failed"] == 0
+    for name, c in r["checks"].items():
+        assert 0 <= c["value"] <= c["limit"], name
+    assert r["_info"]["compiles_in_window"] == 0
+
+
+def test_a_reader_added_as_a_file_reads_the_rounds_metrics(plugged):
+    root, r, ctx = plugged
+    rounds = ctx["round_metrics"]
+    assert len(rounds) == r["attempted"] == len(ctx["round_s"])
+    assert set(rounds[0]) == {"f_s", "f_u", "mask_rate", "k_s", "test_acc"}
+    assert all(math.isfinite(m["f_s"]) for m in rounds)
+    mask = harness.metric_readers(root)["rounds.mask_rate"].read(ctx)
+    assert mask == pytest.approx(
+        100 * np.mean([m["mask_rate"] for m in rounds]))
+    assert 0 <= mask <= 100
 
 
 # ------------------------------------------------------------- refusals

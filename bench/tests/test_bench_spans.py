@@ -169,9 +169,9 @@ def test_a_profiled_round_carries_every_span_nested_on_one_line(
     data = traffic.make_traffic(mix, cfg, 11)
     sys_, _ = harness.make_system(cfg, mix)
     sys_.prefetch = prefetch
-    feed = harness.make_feed(sys_, cell, mix, data, 11)
+    feed = harness.make_feed(sys_, cell, cfg, mix, data, 11)
     state = feed["state"]
-    run = lambda st: sys_.run_round(st, feed["lab"], feed["cls"],
+    run = lambda st: sys_.run_round(st, feed["lab"], feed["clients"],
                                     feed["ctrl"], rng_np=feed["sel"])[0]
     try:
         state = run(state)                          # compile outside

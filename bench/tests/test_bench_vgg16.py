@@ -19,7 +19,8 @@ from bench import harness
 # 4 convs on 16x16, max-pools after convs 1, 2 and 4 (the width rule would
 # pool after 1, 3 and 4), a 2x2 grid spread to 3x3 by the average pool;
 # conv 4 and its pool run in the server's top
-TINY_VGG = dict(arch="bench-tiny-vgg", cnn_channels=[4, 8, 8, 16],
+TINY_VGG = dict(arch="bench-tiny-vgg", family="cnn",
+                cnn_channels=[4, 8, 8, 16],
                 pool_after=[1, 2, 4], pool_to=3, cnn_fc=[16, 16],
                 image_size=16, num_classes=5, split_layer=3, queue_len=64,
                 proj_dim=8, proj_hidden=16, observation_period=2,
@@ -57,7 +58,8 @@ def make_tiny_vgg_root(dest: Path) -> Path:
     as files and one entry, as ``bench_tinycell.make_tiny_root`` adds
     ``tiny``; returns the copy's ``bench`` dir."""
     root = dest / "bench"
-    for sub in ("configs", "traffic", "workloads", "metrics", "reference"):
+    for sub in ("configs", "families", "traffic", "workloads", "metrics",
+                "reference"):
         shutil.copytree(BENCH / sub, root / sub)
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
     cfg = json.loads((root / "configs/vgg16-image100.json").read_text())
@@ -135,9 +137,9 @@ def test_the_control_fails_the_vgg16_comparison(vgg_root, monkeypatch):
     cell, cfg, mix = harness.load_cell("tiny-vgg", vgg_root)
     compared = harness.compared_rounds
 
-    def control(sys_, feed, cell_):
-        prog = compared(sys_, feed, cell_)
-        side = harness.reference_side(cell_, cfg, mix,
+    def control(sys_, feed):
+        prog = compared(sys_, feed)
+        side = harness.reference_side(cell, cfg, mix,
                                       traffic.make_traffic(mix, cfg, SEED),
                                       SEED, root=vgg_root, precision="high")
         return dict(prog, **{k: side[k] for k in ("metrics", "grad",

@@ -1,17 +1,16 @@
-"""Traffic generator: the synthetic image task and its client partition.
+"""Traffic generator: the synthetic task and its client partition.
 
 One general generator reads a traffic mix (``bench/traffic/<name>.json``)
 and a configuration (``bench/configs/<name>.json``) and makes, from the
 seed alone, the data a SemiSFL job trains on: a labeled server set, one
-unlabeled shard per client, and a test set.
+unlabeled shard per client, and a test set.  The samples themselves come
+from the configuration's model family (``bench/families/<family>.py``,
+``make_dataset``); the split and the partition are the same for every
+family.
 
-The image synthesis and the partitioners follow the program's
-``data/synthetic.py`` and ``data/partition.py`` (class prototypes
-upsampled from a quarter-size grid, a random shift, Gaussian noise and a
-brightness offset, clipped to [0, 1]; uniform or Dirichlet(alpha)
-partitions).  They are copied here so that an edit to the program cannot
-move the yardstick.  The images are drawn with NumPy's ``Generator`` in
-float32, which makes 2,800 images of 144x144 in about a second.
+The partitioners follow the program's ``data/partition.py`` (uniform or
+Dirichlet(alpha) partitions), copied here so that an edit to the program
+cannot move the yardstick.
 """
 from __future__ import annotations
 
@@ -19,10 +18,12 @@ from typing import NamedTuple
 
 import numpy as np
 
+from bench.harness import BENCH, family
+
 
 class Dataset(NamedTuple):
-    x: np.ndarray       # (N, H, W, 3) float32 in [0, 1]
-    y: np.ndarray       # (N,) int32
+    x: np.ndarray       # (N, ...) the family's model inputs
+    y: np.ndarray       # (N,) int32 labels
 
 
 class Traffic(NamedTuple):
@@ -30,33 +31,6 @@ class Traffic(NamedTuple):
     test: Dataset
     labeled: np.ndarray         # indices into train of the labeled set
     parts: list                 # per-client indices into train (unlabeled)
-
-
-def _upsample(img: np.ndarray, factor: int) -> np.ndarray:
-    img = np.repeat(np.repeat(img, factor, axis=0), factor, axis=1)
-    k = factor
-    pad = np.pad(img, ((k, k), (k, k), (0, 0)), mode="edge")
-    out = (pad[:-2 * k] + pad[2 * k:] + pad[k:-k]) / 3.0
-    return (out[:, :-2 * k] + out[:, 2 * k:] + out[:, k:-k]) / 3.0
-
-
-def make_images(seed: int, *, num_classes: int, n: int, image_size: int,
-                noise: float = 0.35, max_shift: int = 3) -> Dataset:
-    rng = np.random.default_rng(seed)
-    base = image_size // 4
-    protos = rng.standard_normal((num_classes, base, base, 3),
-                                 dtype=np.float32)
-    protos = np.stack([_upsample(p, 4) for p in protos]).astype(np.float32)
-    protos = (protos - protos.min()) / (np.ptp(protos) + 1e-6)
-    y = rng.integers(0, num_classes, size=n)
-    shifts = rng.integers(-max_shift, max_shift + 1, size=(n, 2))
-    x = np.empty((n, image_size, image_size, 3), np.float32)
-    for i in range(n):
-        x[i] = np.roll(protos[y[i]], tuple(shifts[i]), axis=(0, 1))
-    x += noise * rng.standard_normal(x.shape, dtype=np.float32)
-    x += rng.uniform(-0.15, 0.15, size=(n, 1, 1, 1)).astype(np.float32)
-    np.clip(x, 0.0, 1.0, out=x)
-    return Dataset(x=x, y=y.astype(np.int32))
 
 
 def uniform_partition(rng: np.random.Generator, n: int,
@@ -86,12 +60,11 @@ def dirichlet_partition(rng: np.random.Generator, labels: np.ndarray,
     return [np.sort(o) for o in out]
 
 
-def make_traffic(mix: dict, cfg: dict, seed: int) -> Traffic:
+def make_traffic(mix: dict, cfg: dict, seed: int, root=BENCH) -> Traffic:
     """The whole data set of one run, from the seed.  ``mix`` is a traffic
     file, ``cfg`` a configuration file."""
     n_test, n_train = mix["n_test"], mix["n_train"]
-    ds = make_images(seed, num_classes=cfg["num_classes"],
-                     n=n_train + n_test, image_size=cfg["image_size"])
+    ds = family(cfg, root).make_dataset(cfg, mix, seed, n_train + n_test)
     rng = np.random.default_rng([seed, 1])
     perm = rng.permutation(len(ds.y))
     test, train = perm[:n_test], perm[n_test:]
